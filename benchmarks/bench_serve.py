@@ -59,9 +59,12 @@ def _serve_times(model, params, prompts, gen: int, max_len: int):
     """(warm prefill seconds, warm per-decode-step seconds)."""
     import jax.numpy as jnp
 
+    from repro.exec.dispatch import jit_serving
+
     b, plen = prompts.shape
-    prefill = jax.jit(functools.partial(model.prefill, max_len=max_len))
-    step = jax.jit(model.decode_step, donate_argnums=(1,))
+    prefill = jit_serving(model,
+                          functools.partial(model.prefill, max_len=max_len))
+    step = jit_serving(model, model.decode_step, donate_argnums=(1,))
 
     logits, cache = prefill(params, prompts)        # warm (trace/compile)
     jax.block_until_ready(logits)
@@ -145,7 +148,7 @@ def run(quick: bool = False) -> None:
             if mesh is not None else 1
         ctx = contextlib.nullcontext() if mesh is None else mesh
         rules = contextlib.nullcontext() if mesh is None \
-            else logical_axis_rules(axis_map_for(mesh))
+            else logical_axis_rules(axis_map_for(mesh), mesh=mesh)
         with ctx, rules:
             for label, m in (("dense", model), ("comp", cm)):
                 t_prefill, t_step = _serve_times(m, pruned, prompts, gen,

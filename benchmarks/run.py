@@ -137,4 +137,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import configure_compile_cache
+    configure_compile_cache()
     raise SystemExit(1 if main() else 0)

@@ -9,12 +9,12 @@ import surface (``KernelChoice`` / ``CompressionPlan`` / ``ffn_workload`` /
 
 from __future__ import annotations
 
-from repro.exec.plans import (MXU_ALIGN, CompressionPlan, FallbackReason,
-                              KernelChoice, ffn_workload, plan_for_model,
+from repro.exec.plans import (CompressionPlan, FallbackReason, KernelChoice,
+                              ffn_workload, plan_for_model,
                               translate)
 
 # the seed's private name, kept for callers that reached into it
 _translate = translate
 
-__all__ = ["MXU_ALIGN", "CompressionPlan", "FallbackReason", "KernelChoice",
+__all__ = ["CompressionPlan", "FallbackReason", "KernelChoice",
            "ffn_workload", "plan_for_model", "translate"]

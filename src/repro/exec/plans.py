@@ -37,8 +37,7 @@ from repro.core.primitives import Prim
 from repro.core.sparsity import (NM, Bernoulli, BlockBernoulli, Sparsity,
                                  TensorSpec, analyze)
 from repro.core.workload import MatMul, Workload
-
-MXU_ALIGN = 128
+from repro.kernels import tiling
 
 #: Current :class:`ExecPlan` JSON schema version.  v1 plans (no ``version``
 #: key, no ``checksums``) predate PR 8 and still load; bumping this requires
@@ -106,14 +105,6 @@ class CompressionPlan:
         return self.choices[name]
 
 
-def _align(x: int, extent: int) -> int:
-    """Snap a format level size to an MXU-friendly divisor of extent."""
-    for cand in (x, MXU_ALIGN, 64, 32, 16, 8):
-        if cand and extent % cand == 0 and cand <= extent:
-            return cand
-    return extent
-
-
 def translate(op: MatMul, fmt_w: Optional[Format],
               w_sparsity: Sparsity) -> KernelChoice:
     """One searched W-side format → the kernel that can execute it."""
@@ -133,8 +124,12 @@ def translate(op: MatMul, fmt_w: Optional[Format],
     leaves = {l.dim: int(l.size) for l in fmt_w.levels
               if l.prim is Prim.NONE and l.size is not None}
     if comp and all(l.prim is Prim.B for l in comp):
-        bn = _align(leaves.get("N", MXU_ALIGN), op.N)
-        bk = _align(leaves.get("K", MXU_ALIGN), op.K)
+        # the searched leaves are the granularity; the kernel runs the
+        # nearest Mosaic-executable block (prune/compress use the same one)
+        bn, bk = tiling.bitmap_block(op.N, op.K,
+                                     leaves.get("N", tiling.LANE),
+                                     leaves.get("K", tiling.LANE),
+                                     value_bytes=op.value_bits // 8)
         spec = TensorSpec(op.w_dims(), w_sparsity, op.value_bits)
         ratio = analyze(fmt_w, spec).total_bits / spec.dense_bits
         return KernelChoice(op.name, "bitmap", bn, bk,

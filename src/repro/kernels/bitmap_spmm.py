@@ -38,8 +38,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 
 def _kernel(counts_ref, offs_ref, rows_ref, x_ref, w_ref, y_ref):
     kj = pl.program_id(1)
@@ -128,14 +126,14 @@ def _bitmap_spmm_pipelined(x: jax.Array, blocks: jax.Array,
             num_scalar_prefetch=3,
             grid=(m // bm, gk),
             in_specs=[
-                pl.BlockSpec(memory_space=pltpu.ANY),
-                pl.BlockSpec(memory_space=pltpu.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=pl.BlockSpec((bm, bk), lambda mi, kj, *_: (mi, kj)),
         ),
         out_shape=jax.ShapeDtypeStruct((m, k), jnp.float32),
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
     )(counts, offsets, row_ids, x3, blocks)
 
@@ -199,6 +197,6 @@ def bitmap_spmm_pallas(x: jax.Array, blocks: jax.Array, counts: jax.Array,
         ),
         out_shape=jax.ShapeDtypeStruct((m, k), jnp.float32),
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(counts, offsets, row_ids, x, blocks)

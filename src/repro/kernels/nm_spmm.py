@@ -31,8 +31,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 
 def _decode_tile(wc, idx, *, n_sel: int, m_group: int):
     """Expand a compressed (bh, bk) tile to its dense (bn, bk) operand via
@@ -135,11 +133,11 @@ def _nm_spmm_pipelined(x: jax.Array, wc: jax.Array, idx: jax.Array,
     return pl.pallas_call(
         kernel,
         grid=(m // bm, k // bk),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)] * 3,
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 3,
         out_specs=pl.BlockSpec((bm, bk), lambda mi, kj: (mi, kj)),
         out_shape=jax.ShapeDtypeStruct((m, k), jnp.float32),
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
     )(x3, wc3, idx3)
 
@@ -175,6 +173,6 @@ def nm_spmm_pallas(x: jax.Array, wc: jax.Array, idx: jax.Array,
         out_specs=pl.BlockSpec((bm, bk), lambda mi, kj, ni: (mi, kj)),
         out_shape=jax.ShapeDtypeStruct((m, k), jnp.float32),
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(x, wc, idx)

@@ -1,9 +1,15 @@
 """Jit'd public wrappers for the sparse kernels.
 
-On CPU (this container) the Pallas kernels run in ``interpret=True`` mode;
-on TPU they compile natively.  Compression runs host-side (numpy) — it is
-the SnipSnap format decoder's software half: the chosen format's metadata
-becomes scalar-prefetch arrays whose layout mirrors the kernel tiling.
+On the CPU backend (the test suite) the Pallas kernels run in
+``interpret=True`` mode; on TPU Mosaic compiles them; any other backend
+raises.  Compression runs host-side (numpy) — it is the SnipSnap format
+decoder's software half: the chosen format's metadata becomes
+scalar-prefetch arrays whose layout mirrors the kernel tiling.
+
+Row tiles follow :mod:`repro.kernels.tiling`: the wrappers pad the
+activation's rows to a multiple of a sublane-aligned tile inside the jitted
+call and slice the padding off the output, so any row count — one decode
+slot, a 37-token prompt — runs on tiles Mosaic accepts.
 
 The jitted wrappers are CACHED per static-knob tuple (``_jitted``): the
 seed rebuilt ``jax.jit(functools.partial(...))`` on every call, which made
@@ -40,13 +46,21 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels import ref
+from repro.kernels import ref, tiling
 from repro.kernels.bitmap_spmm import bitmap_spmm_pallas
 from repro.kernels.nm_spmm import nm_spmm_pallas
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Interpret mode on the CPU backend, Mosaic on TPU; no other backend
+    has a kernel path, and running one in the interpreter there would pass
+    for a device run."""
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(
+            f"no Pallas kernel path for the {backend!r} backend: the sparse "
+            f"kernels compile for TPU and run in interpret mode on CPU only")
+    return backend == "cpu"
 
 
 _PIPELINE_DEFAULT = True
@@ -179,6 +193,36 @@ def clear_kernel_cache() -> None:
     _JIT_STATS["hits"] = _JIT_STATS["misses"] = 0
 
 
+def wrapper_keys() -> list[tuple]:
+    """Keys of the cached wrappers: ``(kind, *static)``, whose last static
+    knob is ``interpret`` — so a run can show that every kernel it built
+    went through Mosaic."""
+    return list(_JIT_CACHE)
+
+
+def _row_tile(x: jax.Array, bm: int | None) -> int:
+    """The row tile a call runs: ``bm`` (default: :func:`tiling.row_tile`)
+    rounded up to the activation dtype's sublane multiple, and no taller
+    than the padded activation."""
+    m = x.shape[0]
+    if bm is None:
+        return tiling.row_tile(m, x.dtype.itemsize)
+    sub = tiling.sublane(x.dtype.itemsize)
+    return -(-min(bm, max(m, 1)) // sub) * sub
+
+
+def _padded_rows(kernel, bm: int):
+    """``kernel(x, *operands)`` over ``x`` zero-padded to a multiple of
+    ``bm`` rows, with the padding sliced off the (M, K) output."""
+    def call(x, *operands):
+        m = x.shape[0]
+        pad = -m % bm
+        if pad:
+            x = jnp.pad(x, ((0, pad), (0, 0)))
+        return kernel(x, *operands)[:m]
+    return call
+
+
 # ---------------------------------------------------------------------------
 # Bitmap block-sparse
 # ---------------------------------------------------------------------------
@@ -217,14 +261,18 @@ def compress_bitmap(w, bn: int = 128, bk: int = 128) -> BitmapCompressed:
 
 def _bitmap_builder(k: int, bm: int, t_max: int, pipeline: bool,
                     interpret: bool):
-    return functools.partial(bitmap_spmm_pallas, k=k, bm=bm, t_max=t_max,
-                             pipeline=pipeline, interpret=interpret)
+    return _padded_rows(
+        functools.partial(bitmap_spmm_pallas, k=k, bm=bm, t_max=t_max,
+                          pipeline=pipeline, interpret=interpret), bm)
 
 
-def bitmap_spmm(x: jax.Array, w: BitmapCompressed, bm: int = 128,
+def bitmap_spmm(x: jax.Array, w: BitmapCompressed, bm: int | None = None,
                 t_max: int | None = None,
                 pipeline: bool | None = None) -> jax.Array:
     """Y = X @ W_blocksparse; dispatches to the Pallas kernel.
+
+    ``bm`` is the row tile (default :func:`tiling.row_tile` of the row
+    count); rows pad to a multiple of it inside the call.
 
     ``t_max`` (default: ``w.max_per_col``) is part of the static cache key,
     so the naive path's innermost grid bound is always the statically-known
@@ -239,8 +287,8 @@ def bitmap_spmm(x: jax.Array, w: BitmapCompressed, bm: int = 128,
     _fault_check("bitmap")
     if t_max is None:
         t_max = w.max_per_col
-    fn = _jitted("bitmap", _bitmap_builder, w.k, bm, max(int(t_max), 1),
-                 resolve_pipeline(pipeline), _interpret())
+    fn = _jitted("bitmap", _bitmap_builder, w.k, _row_tile(x, bm),
+                 max(int(t_max), 1), resolve_pipeline(pipeline), _interpret())
     return _dispatch("bitmap", fn, x, w.blocks, w.counts, w.row_ids,
                      w.offsets)
 
@@ -273,16 +321,23 @@ def compress_nm(w, n_sel: int = 2, m_group: int = 4) -> NMCompressed:
 
 def _nm_builder(n_sel: int, m_group: int, bm: int, bn: int, bk: int,
                 pipeline: bool, interpret: bool):
-    return functools.partial(nm_spmm_pallas, n_sel=n_sel, m_group=m_group,
-                             bm=bm, bn=bn, bk=bk, pipeline=pipeline,
-                             interpret=interpret)
+    return _padded_rows(
+        functools.partial(nm_spmm_pallas, n_sel=n_sel, m_group=m_group,
+                          bm=bm, bn=bn, bk=bk, pipeline=pipeline,
+                          interpret=interpret), bm)
 
 
-def nm_spmm(x: jax.Array, w: NMCompressed, bm: int = 128, bn: int = 128,
-            bk: int = 128, pipeline: bool | None = None) -> jax.Array:
+def nm_spmm(x: jax.Array, w: NMCompressed, bm: int | None = None,
+            bn: int | None = None, bk: int | None = None,
+            pipeline: bool | None = None) -> jax.Array:
+    """Y = X @ W_nm; dispatches to the Pallas kernel.  ``bm`` as in
+    :func:`bitmap_spmm`; ``bn`` / ``bk`` default to the widest
+    lane-aligned tiles of at most 128 (:func:`tiling.lane_tile`)."""
     _fault_check("nm")
-    fn = _jitted("nm", _nm_builder, w.n_sel, w.m_group, bm, bn, bk,
-                 resolve_pipeline(pipeline), _interpret())
+    bn = tiling.lane_tile(w.n) if bn is None else bn
+    bk = tiling.lane_tile(w.k) if bk is None else bk
+    fn = _jitted("nm", _nm_builder, w.n_sel, w.m_group, _row_tile(x, bm),
+                 bn, bk, resolve_pipeline(pipeline), _interpret())
     return _dispatch("nm", fn, x, w.values, w.indices)
 
 

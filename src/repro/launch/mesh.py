@@ -15,10 +15,20 @@ from typing import Optional
 import jax
 
 
+def _make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
+    """``jax.make_mesh`` with Auto axes: the model zoo places activations
+    with logical ``with_sharding_constraint`` annotations and lets XLA's
+    partitioner propagate the rest, which the Explicit axes ``make_mesh``
+    defaults to would refuse (an embedding gather over a batch-sharded
+    index already asks for an explicit out-sharding)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _make_mesh(shape, axes)
 
 
 def mesh_axis_sizes(mesh) -> dict[str, int]:
@@ -45,7 +55,7 @@ def make_small_mesh(data: int = 1, model: int = 1) -> Optional[object]:
     n = data * model
     if len(jax.devices()) < n:
         return None
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _make_mesh((data, model), ("data", "model"))
 
 
 def make_serve_mesh(batch: int, model: int = 1) -> Optional[object]:
@@ -62,7 +72,7 @@ def make_serve_mesh(batch: int, model: int = 1) -> Optional[object]:
     data = math.gcd(max(batch, 1), avail // model)
     if data * model <= 1:
         return None
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _make_mesh((data, model), ("data", "model"))
 
 
 def degraded_serve_mesh(batch: int, lost: int, model: int = 1

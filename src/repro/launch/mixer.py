@@ -56,6 +56,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.exec.dispatch import jit_serving
 from repro.obs import metrics as omet
 from repro.obs import trace as otr
 from repro.runtime.fault import StragglerMonitor
@@ -213,10 +214,11 @@ class Mixer:
                 raise NotImplementedError(
                     f"mixer: cache leaf {leaf.shape} does not carry the "
                     f"slot axis at position 1; family unsupported")
-        self._step_fn = jax.jit(model.decode_step, donate_argnums=(1,))
-        self._prefill_fn = jax.jit(
-            functools.partial(model.prefill, max_len=max_len))
-        self._ingest_fn = jax.jit(model.decode_step)
+        self._step_fn = jit_serving(model, model.decode_step,
+                                    donate_argnums=(1,))
+        self._prefill_fn = jit_serving(
+            model, functools.partial(model.prefill, max_len=max_len))
+        self._ingest_fn = jit_serving(model, model.decode_step)
         self._write_fn = jax.jit(write_slot, donate_argnums=(0,))
 
         # host-side per-slot state

@@ -35,6 +35,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
+from repro.exec.dispatch import jit_serving
+from repro.launch.compile_cache import configure_compile_cache
 from repro.launch.mesh import axis_map_for, make_serve_mesh, mesh_axis_sizes
 from repro.models.sharding import logical_axis_rules, named_sharding
 from repro.models.transformer import Model
@@ -83,7 +85,7 @@ def _generate(model, params, prompts: jax.Array, gen: int, max_len: int,
         raise ValueError(f"prompt ({plen}) + gen ({gen}) exceeds "
                          f"max_len ({max_len})")
     offsets = _prompt_offsets(prompts, prompt_pad_id)
-    step = jax.jit(model.decode_step, donate_argnums=(1,))
+    step = jit_serving(model, model.decode_step, donate_argnums=(1,))
     tid = otr.trace_id()
 
     t0 = time.perf_counter()
@@ -113,8 +115,8 @@ def _generate(model, params, prompts: jax.Array, gen: int, max_len: int,
         else:
             pos = None                                     # lockstep scalar
             try:
-                prefill = jax.jit(functools.partial(model.prefill,
-                                                    max_len=max_len))
+                prefill = jit_serving(
+                    model, functools.partial(model.prefill, max_len=max_len))
                 all_logits, cache = prefill(params, prompts)
                 logits = all_logits[:, -1]
                 jax.block_until_ready(logits)
@@ -194,7 +196,7 @@ def generate(model, params, prompts: jax.Array, gen: int, max_len: int,
         return _generate(model, params, prompts, gen, max_len,
                          eos_id=eos_id, pad_id=pad_id,
                          prompt_pad_id=prompt_pad_id)
-    with mesh, logical_axis_rules(axis_map_for(mesh)):
+    with mesh, logical_axis_rules(axis_map_for(mesh), mesh=mesh):
         prompts = jax.device_put(prompts,
                                  named_sharding(mesh, "batch", None))
         return _generate(model, params, prompts, gen, max_len,
@@ -270,6 +272,7 @@ def main() -> None:
                          "to PATH plus Prometheus text exposition to "
                          "PATH.prom")
     args = ap.parse_args()
+    configure_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -21,19 +21,27 @@ def _axis_map() -> Optional[dict]:
     return getattr(_state, "axis_map", None)
 
 
+def bound_mesh():
+    """The mesh the current :func:`logical_axis_rules` scope binds to, or
+    None.  Code that XLA cannot partition by itself (a Pallas kernel, a
+    Mosaic custom call) places itself on it with ``shard_map``."""
+    return getattr(_state, "mesh", None)
+
+
 @contextlib.contextmanager
-def logical_axis_rules(axis_map: dict):
+def logical_axis_rules(axis_map: dict, mesh=None):
     """Bind logical axis names → mesh axis names (or None) for the scope.
 
     ``axis_map`` example: {"batch": ("pod", "data"), "model": "model",
-    "seq": None, "vocab": "model", "expert": "model"}.
+    "seq": None, "vocab": "model", "expert": "model"}.  ``mesh`` is the
+    concrete mesh those names live on (see :func:`bound_mesh`).
     """
-    prev = _axis_map()
-    _state.axis_map = axis_map
+    prev = _axis_map(), bound_mesh()
+    _state.axis_map, _state.mesh = axis_map, mesh
     try:
         yield
     finally:
-        _state.axis_map = prev
+        _state.axis_map, _state.mesh = prev
 
 
 def shard(x: jax.Array, *logical_axes: Optional[str]) -> jax.Array:

@@ -4,9 +4,9 @@ Two layers, both off by default and free when off:
 
   * :func:`jax_trace` — wraps a region in a ``jax.profiler`` trace capture
     (TensorBoard/Perfetto-loadable artifacts under ``log_dir``).  A no-op
-    when ``log_dir`` is falsy, best-effort when the profiler backend is
-    unavailable (interpret-mode CPU containers) — serving never fails
-    because profiling could not start.
+    when ``log_dir`` is falsy; when a trace was asked for and the profiler
+    cannot start, it raises — a run that was meant to be traced does not
+    quietly go on untraced.
   * :func:`kernel_timer` — installs a
     :func:`repro.kernels.ops.kernel_dispatch_hook` (the observation twin
     of the fault-injection ``kernel_fault_hook``) that records every
@@ -31,35 +31,24 @@ from repro.obs import trace as _trace
 @contextlib.contextmanager
 def jax_trace(log_dir: Optional[str]) -> Iterator[None]:
     """Capture a ``jax.profiler`` trace of the region into ``log_dir``
-    (no-op when ``log_dir`` is None/empty, tolerant of missing backends)."""
+    (no-op when ``log_dir`` is None/empty).  A profiler that cannot start
+    raises from ``start_trace``."""
     if not log_dir:
         yield
         return
     import jax
-    started = False
-    try:
-        jax.profiler.start_trace(str(log_dir))
-        started = True
-    except Exception:  # noqa: BLE001 — profiling is best-effort by contract
-        pass
+    jax.profiler.start_trace(str(log_dir))
     try:
         yield
     finally:
-        if started:
-            try:
-                jax.profiler.stop_trace()
-            except Exception:  # noqa: BLE001
-                pass
+        jax.profiler.stop_trace()
 
 
 def annotation(name: str):
     """A ``jax.profiler.TraceAnnotation`` naming a region inside a
-    :func:`jax_trace` capture (null context when unavailable)."""
+    :func:`jax_trace` capture."""
     import jax
-    try:
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:  # noqa: BLE001
-        return contextlib.nullcontext()
+    return jax.profiler.TraceAnnotation(name)
 
 
 @contextlib.contextmanager

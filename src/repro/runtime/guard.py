@@ -225,7 +225,7 @@ def guarded_generate(model, params, prompts: jax.Array, gen: int,
                 cm = cm.demoted(errors)
 
         if mesh is not None:
-            with mesh, logical_axis_rules(axis_map_for(mesh)):
+            with mesh, logical_axis_rules(axis_map_for(mesh), mesh=mesh):
                 prompts = jax.device_put(prompts,
                                          named_sharding(mesh, "batch", None))
                 toks = _drive(cm, dense_model, params, prompts, gen, max_len,
@@ -250,7 +250,7 @@ def _drive(cm, dense, params, prompts, gen: int, max_len: int,
 
     import numpy as np
 
-    from repro.exec.dispatch import kernel_guard
+    from repro.exec.dispatch import jit_serving, kernel_guard
 
     b, plen = prompts.shape
     tid = report.trace_id
@@ -267,10 +267,10 @@ def _drive(cm, dense, params, prompts, gen: int, max_len: int,
 
     # the pre-step cache must survive a retry AND the dense fallback's
     # re-step, so — unlike the unguarded driver — no donate_argnums here
-    step_c = jax.jit(cm.decode_step)
+    step_c = jit_serving(cm, cm.decode_step)
     step_d = None
     if dense is not None and dense is not cm:
-        step_d = jax.jit(dense.decode_step)
+        step_d = jit_serving(dense, dense.decode_step)
     guard = fault.StepGuard(max_retries=max_retries,
                             on_restore=lambda: None)
     dense_guard = fault.StepGuard(max_retries=max_retries,
@@ -322,8 +322,8 @@ def _drive(cm, dense, params, prompts, gen: int, max_len: int,
         # ---- prefill (guarded; falls back to guarded token ingest) --------
         with obs_trace.span("prefill", trace_id=tid, batch=b, plen=plen):
             t0 = time.perf_counter()
-            prefill_c = jax.jit(functools.partial(cm.prefill,
-                                                  max_len=max_len))
+            prefill_c = jit_serving(
+                cm, functools.partial(cm.prefill, max_len=max_len))
 
             def attempt_prefill():
                 try:
@@ -351,8 +351,8 @@ def _drive(cm, dense, params, prompts, gen: int, max_len: int,
                                            detail=last)
                     obs_trace.event("dense_switch", trace_id=tid, pos=-1,
                                     code=_failure_code(last))
-                    prefill_d = jax.jit(functools.partial(dense.prefill,
-                                                          max_len=max_len))
+                    prefill_d = jit_serving(dense, functools.partial(
+                        dense.prefill, max_len=max_len))
                     all_lg, cache = prefill_d(params, prompts)
                     logits = all_lg[:, -1]
                     if not _finite(logits):

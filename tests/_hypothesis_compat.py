@@ -1,83 +1,7 @@
-"""``hypothesis`` compatibility layer for the property-test modules.
+"""One import point for the property-test modules' ``hypothesis`` API:
+``given`` / ``settings`` / ``st``."""
 
-When hypothesis is installed, this re-exports the real ``given`` /
-``settings`` / ``st``.  When it is not (the package cannot be installed in
-every environment this suite runs in), a minimal fallback runs each
-property against a FIXED-SEED set of pseudo-random examples, so the
-modules still collect and exercise the invariants everywhere — just
-without shrinking or example databases.
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
-Only the strategy constructors the suite actually uses are shimmed:
-``st.floats(lo, hi)``, ``st.integers(lo, hi)``, ``st.sampled_from(seq)``,
-``st.booleans()``.
-"""
-
-from __future__ import annotations
-
-import random
-
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-
-    HAVE_HYPOTHESIS = True
-except ImportError:
-    HAVE_HYPOTHESIS = False
-
-    _DEFAULT_EXAMPLES = 10
-    _SEED = 0x5EED
-
-    class _Strategy:
-        def __init__(self, draw):
-            self._draw = draw
-
-        def example(self, rng: random.Random):
-            return self._draw(rng)
-
-    class _Strategies:
-        @staticmethod
-        def floats(min_value, max_value):
-            return _Strategy(lambda r: r.uniform(min_value, max_value))
-
-        @staticmethod
-        def integers(min_value, max_value):
-            return _Strategy(lambda r: r.randint(min_value, max_value))
-
-        @staticmethod
-        def sampled_from(elements):
-            elements = list(elements)
-            return _Strategy(lambda r: elements[r.randrange(len(elements))])
-
-        @staticmethod
-        def booleans():
-            return _Strategy(lambda r: bool(r.getrandbits(1)))
-
-    st = _Strategies()
-
-    def given(**strategies):
-        def deco(fn):
-            # NOTE: not functools.wraps — pytest would follow __wrapped__
-            # to the original signature and demand fixtures for the
-            # strategy parameters.  The wrapper must look zero-argument.
-            def wrapper():
-                rng = random.Random(_SEED)
-                n = getattr(wrapper, "_max_examples", _DEFAULT_EXAMPLES)
-                for _ in range(n):
-                    drawn = {k: s.example(rng) for k, s in strategies.items()}
-                    fn(**drawn)
-
-            wrapper.__name__ = fn.__name__
-            wrapper.__doc__ = fn.__doc__
-            wrapper.__module__ = fn.__module__
-            wrapper._is_fallback_property = True
-            return wrapper
-
-        return deco
-
-    def settings(max_examples=_DEFAULT_EXAMPLES, deadline=None, **_ignored):
-        def deco(fn):
-            if getattr(fn, "_is_fallback_property", False):
-                fn._max_examples = max_examples
-            return fn
-
-        return deco
+__all__ = ["given", "settings", "st"]

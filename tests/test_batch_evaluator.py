@@ -13,7 +13,7 @@ import math
 import types
 
 import pytest
-from _hypothesis_compat import given, settings, st  # hypothesis or fallback
+from _hypothesis_compat import given, settings, st
 
 import numpy as np
 

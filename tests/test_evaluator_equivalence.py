@@ -10,15 +10,12 @@ format row) index triples over a packed table + fetch tables, and the
 (``eval_threads``).  These properties pin the contract on RANDOM op
 shapes, sparsity levels, and format allocations: all paths are
 BIT-identical — every metric and every breakdown term, not just energy.
-
-Runs under real ``hypothesis`` when installed, else the fixed-seed
-fallback in ``tests/_hypothesis_compat.py``.
 """
 
 import dataclasses
 
 import numpy as np
-from _hypothesis_compat import given, settings, st  # hypothesis or fallback
+from _hypothesis_compat import given, settings, st
 
 from repro.core import memo
 from repro.core.arch import ARCH2, ARCH3

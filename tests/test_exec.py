@@ -48,6 +48,14 @@ def _cfg():
     return get_config("chatglm3-6b").reduced()
 
 
+def _lane_cfg():
+    """The smallest chatglm3-6b cut in which every projection spans two
+    lane-aligned (128-multiple) bitmap blocks, so block pruning can realize
+    the plan's 0.5 density: in ``_cfg()`` the 128-wide attention weights are
+    one Mosaic block each and stay whole."""
+    return dataclasses.replace(_cfg(), d_model=512, d_head=64, d_ff=1024)
+
+
 def _plan(cfg, sp):
     return rexec.build_exec_plan(cfg, sp, tokens=64, search_cfg=FAST,
                                  value_bits=32)
@@ -107,6 +115,27 @@ def test_exec_plan_json_roundtrip_bit_identical(tmp_path):
     assert loaded.to_json() == plan.to_json()
 
 
+def test_translate_blocks_lane_aligned_and_vmem_capped_at_published_widths():
+    """At chatglm3-6b's widths the searched leaves (1024x4096, 856x4096,
+    ...) are refused by Mosaic; translate serves every role with
+    128-multiple blocks that divide the extents and fit the VMEM budget,
+    and keeps the searched format string."""
+    from repro.kernels import tiling
+    from repro.launch.serve import _fast_plan
+    cfg = dataclasses.replace(get_config("chatglm3-6b"), n_layers=4)
+    plan = _fast_plan(cfg, tokens=64)
+    assert {op.role for op in plan.ops} == \
+        {r.role for r in cfg.matmul_roles()}
+    for op in plan.ops:
+        ch = op.choice
+        assert ch.kind == "bitmap" and ch.format_str.count("B(") == 1
+        for block, extent in ((ch.block_n, op.n), (ch.block_k, op.k)):
+            assert block % tiling.LANE == 0 and extent % block == 0, op
+        assert tiling.bitmap_tile_bytes(
+            ch.block_n, ch.block_k, plan.value_bits // 8) \
+            <= tiling.VMEM_BUDGET_BYTES, op
+
+
 def test_fallback_reason_recorded_for_unservable_formats():
     op = MatMul("w", 64, 128, 128, Bernoulli(0.5), Bernoulli(0.3))
     rle = standard_formats({"N": 128, "K": 128})["RLE"]
@@ -131,7 +160,7 @@ def test_fallback_reason_recorded_for_unservable_formats():
 # ---------------------------------------------------------------------------
 
 def test_compress_store_exact_ratio_accounting():
-    cfg = _cfg()
+    cfg = _lane_cfg()
     model, plan, pruned, store = _serving(cfg, BLOCK)
     assert len(store) == cfg.n_layers * len(plan.ops)
     for e in store:
@@ -225,7 +254,7 @@ def test_kernel_wrapper_cache_reuses_jit():
 def test_end_to_end_plan_dispatch_calibration(fp32_compute):
     """Searched plan → compressed forward → measured counters vs the cost
     model's predicted fetch terms, within the calibrated bound."""
-    cfg = _cfg()
+    cfg = _lane_cfg()
     model, plan, pruned, store = _serving(cfg, BLOCK)
     tokens = _tokens(cfg)
     dense_out = model.hidden_states(pruned, tokens, remat=False)

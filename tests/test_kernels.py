@@ -5,7 +5,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st  # hypothesis or fallback
+from _hypothesis_compat import given, settings, st
 
 from repro.kernels import ops, ref
 
@@ -179,3 +179,72 @@ def test_flash_attention_bf16():
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
                                rtol=5e-2, atol=5e-2)
+
+
+# ---------------------------------------------------------------------------
+# row padding, tile rules, backend guard
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("pipeline", [True, False])
+@pytest.mark.parametrize("m", [1, 3, 37])
+def test_bitmap_spmm_padded_rows_match_unpadded(m, pipeline):
+    """The wrapper pads m bf16 rows to a 16-row multiple and slices the
+    padding off: the result equals the raw kernel run on the m rows."""
+    from repro.kernels.bitmap_spmm import bitmap_spmm_pallas
+    rng = np.random.default_rng(m)
+    w, _ = _block_sparse_w(rng, 64, 64, 16, 16, 0.5)
+    x = jnp.asarray(rng.normal(size=(m, 64)), jnp.bfloat16)
+    comp = ops.compress_bitmap(w, 16, 16)
+    got = ops.bitmap_spmm(x, comp, pipeline=pipeline)
+    want = bitmap_spmm_pallas(x, comp.blocks, comp.counts, comp.row_ids,
+                              comp.offsets, k=64, bm=m,
+                              t_max=comp.max_per_col, interpret=True,
+                              pipeline=pipeline)
+    assert got.shape == (m, 64)
+    # a 1-row matmul takes XLA:CPU's matrix-vector path: same f32 sums in
+    # another order, so allow f32 rounding over the 64-term contraction
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("pipeline", [True, False])
+@pytest.mark.parametrize("m", [1, 3, 37])
+def test_nm_spmm_padded_rows_match_unpadded(m, pipeline):
+    from repro.kernels.nm_spmm import nm_spmm_pallas
+    rng = np.random.default_rng(m)
+    w = _nm_sparse_w(rng, 64, 32)
+    x = jnp.asarray(rng.normal(size=(m, 64)), jnp.bfloat16)
+    comp = ops.compress_nm(w)
+    got = ops.nm_spmm(x, comp, pipeline=pipeline)
+    want = nm_spmm_pallas(x, comp.values, comp.indices, bm=m, bn=64, bk=32,
+                          interpret=True, pipeline=pipeline)
+    assert got.shape == (m, 32)
+    # a 1-row matmul takes XLA:CPU's matrix-vector path: same f32 sums in
+    # another order, so allow f32 rounding over the 64-term contraction
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_row_and_lane_tiles_follow_mosaic_tiling():
+    from repro.kernels import tiling
+    # fewest ≤128-row tiles, each a multiple of the sublane packing
+    bf16 = {m: tiling.row_tile(m, 2) for m in (1, 3, 8, 37, 130, 200, 512)}
+    assert bf16 == {1: 16, 3: 16, 8: 16, 37: 48, 130: 80, 200: 112,
+                    512: 128}
+    assert tiling.row_tile(3, 4) == 8 and tiling.row_tile(3, 1) == 32
+    # lane blocks: 128-multiples that divide, else the whole axis
+    assert tiling.lane_divisors(13696) == [128, 13696]
+    assert tiling.lane_divisors(512) == [128, 256, 512]
+    assert tiling.lane_divisors(64) == [64]
+    assert tiling.lane_tile(4096) == 128 and tiling.lane_tile(64) == 64
+
+
+def test_interpret_mode_only_on_cpu(monkeypatch):
+    """Interpret mode is the CPU's; TPU compiles; anything else raises
+    instead of quietly interpreting."""
+    for backend, interpret in (("cpu", True), ("tpu", False)):
+        monkeypatch.setattr(jax, "default_backend", lambda b=backend: b)
+        assert ops._interpret() is interpret
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="'gpu'"):
+        ops._interpret()

@@ -454,7 +454,10 @@ def test_padding_overhead_extreme_accounted_exactly(fp32_compute):
     accounting matches a by-hand recomputation from the per-layer store,
     and the padded scanned forward still decodes bit-identically to the
     per-layer dispatch."""
-    cfg = get_config("chatglm3-6b").reduced()
+    # widths at which every projection spans two lane-aligned blocks: the
+    # 128-wide attention weights of reduced() are one block and stay whole
+    cfg = dataclasses.replace(get_config("chatglm3-6b").reduced(),
+                              d_model=512, d_head=64, d_ff=1024)
     model, plan, mixed, store = _mixed_serving(cfg)
     st = stack_store(store)
 

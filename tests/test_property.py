@@ -10,7 +10,7 @@ import math
 
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st  # hypothesis or fallback
+from _hypothesis_compat import given, settings, st
 
 from repro.core import formats as F
 from repro.core.arch import ARCH2, ARCH3

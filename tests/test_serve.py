@@ -286,3 +286,45 @@ def test_make_serve_mesh_degenerate_cases():
         assert mesh is not None
         sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
         assert sizes["data"] > 1 and ndev % sizes["data"] == 0
+
+
+def test_compile_cache_helper(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins untouched; otherwise the cache sits
+    at the fixed in-checkout path."""
+    from repro.launch import compile_cache as cc
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        jax.config.update("jax_compilation_cache_dir", None)
+        monkeypatch.setenv(cc.ENV_VAR, "/elsewhere/jax-cache")
+        assert cc.configure_compile_cache() == "/elsewhere/jax-cache"
+        assert jax.config.jax_compilation_cache_dir is None
+        monkeypatch.delenv(cc.ENV_VAR)
+        path = cc.configure_compile_cache()
+        assert path == str(cc.CHECKOUT / ".jax_cache")
+        assert (cc.CHECKOUT / "src" / "repro" / "launch").is_dir()
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+
+
+def test_compressed_generate_under_mesh_runs_kernels_per_shard():
+    """Under a bound serving mesh the Pallas kernels sit inside shard_map
+    (XLA cannot partition a Mosaic call): tokens equal the unmeshed run."""
+    import functools
+
+    from repro.launch.mesh import _make_mesh, axis_map_for
+    from repro.models.sharding import logical_axis_rules
+
+    cfg = _cfg()
+    params = Model(cfg).init(jax.random.key(0))
+    cm, pruned = serve.compressed_model(cfg, params)
+    prompts = jnp.asarray(np.random.default_rng(0).integers(
+        0, cfg.vocab, (2, 8)), jnp.int32)
+    mesh = _make_mesh((1, 1), ("data", "model"))
+    with mesh, logical_axis_rules(axis_map_for(mesh), mesh=mesh):
+        jaxpr = jax.make_jaxpr(functools.partial(cm.prefill, max_len=12))(
+            pruned, prompts)
+    assert "shard_map" in str(jaxpr)
+    meshed = serve.generate(cm, pruned, prompts, 4, 12, mesh=mesh)[0]
+    plain = serve.generate(cm, pruned, prompts, 4, 12)[0]
+    np.testing.assert_array_equal(np.asarray(meshed), np.asarray(plain))
