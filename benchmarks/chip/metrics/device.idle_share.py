@@ -1,0 +1,6 @@
+"""Share of the traced window in which no operation ran on the device, %."""
+
+
+def read(ctx):
+    tr = ctx["trace"]
+    return 100.0 * (1.0 - tr.busy_s / tr.window_s) if tr.window_s else None
