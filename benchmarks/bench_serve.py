@@ -223,20 +223,17 @@ def run(quick: bool = False) -> None:
          f"tokens_match={bool(jnp.all(toks_u == toks_g))}")
 
     # observability row: the SAME static generate with telemetry off vs
-    # with a tracer + metrics registry + kernel timer installed
-    # (repro.obs).  The off path must stay bit-identical — the span/event
-    # helpers reduce to one None check — and the on path's ratio is the
-    # plane's real cost; both sides are warm (the guarded row above
-    # already traced this shape)
+    # with a tracer + metrics registry installed (repro.obs).  The off
+    # path must stay bit-identical — the span/event helpers reduce to one
+    # None check — and the on path's ratio is the plane's real cost; both
+    # sides are warm (the guarded row above already traced this shape)
     from repro.obs import metrics as omet
     from repro.obs import trace as otr
-    from repro.obs.profile import kernel_timer
     toks_off, _, t_off = serve_mod.generate(cm, pruned, prompts, gen,
                                             plen + gen)
     tracer = otr.Tracer()
     reg = omet.MetricsRegistry()
-    with otr.tracing(tracer), omet.collecting(reg), \
-            kernel_timer(registry=reg, tracer=tracer):
+    with otr.tracing(tracer), omet.collecting(reg):
         toks_on, _, t_on = serve_mod.generate(cm, pruned, prompts, gen,
                                               plen + gen)
     snap = reg.snapshot()

@@ -133,6 +133,7 @@ def _bitmap_spmm_pipelined(x: jax.Array, blocks: jax.Array,
         ),
         out_shape=jax.ShapeDtypeStruct((m, k), jnp.float32),
         interpret=interpret,
+        name="bitmap_spmm_pipelined",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
     )(counts, offsets, row_ids, x3, blocks)
@@ -197,6 +198,7 @@ def bitmap_spmm_pallas(x: jax.Array, blocks: jax.Array, counts: jax.Array,
         ),
         out_shape=jax.ShapeDtypeStruct((m, k), jnp.float32),
         interpret=interpret,
+        name="bitmap_spmm",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(counts, offsets, row_ids, x, blocks)

@@ -137,6 +137,7 @@ def _nm_spmm_pipelined(x: jax.Array, wc: jax.Array, idx: jax.Array,
         out_specs=pl.BlockSpec((bm, bk), lambda mi, kj: (mi, kj)),
         out_shape=jax.ShapeDtypeStruct((m, k), jnp.float32),
         interpret=interpret,
+        name="nm_spmm_pipelined",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
     )(x3, wc3, idx3)
@@ -173,6 +174,7 @@ def nm_spmm_pallas(x: jax.Array, wc: jax.Array, idx: jax.Array,
         out_specs=pl.BlockSpec((bm, bk), lambda mi, kj, ni: (mi, kj)),
         out_shape=jax.ShapeDtypeStruct((m, k), jnp.float32),
         interpret=interpret,
+        name="nm_spmm",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(x, wc, idx)

@@ -40,7 +40,6 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import functools
-import time
 
 import jax
 import jax.numpy as jnp
@@ -122,41 +121,6 @@ def kernel_fault_hook(fn):
 def _fault_check(kind: str) -> None:
     if _FAULT_HOOK is not None:
         _FAULT_HOOK(kind)
-
-
-# ---------------------------------------------------------------------------
-# Kernel dispatch hook (per-dispatch timing for the observability plane)
-# ---------------------------------------------------------------------------
-
-_DISPATCH_HOOK = None
-
-
-@contextlib.contextmanager
-def kernel_dispatch_hook(fn):
-    """Install a hook called as ``fn(kind, seconds)`` after every kernel
-    dispatch (``kind`` ∈ {"bitmap", "nm", "flash"}) — the observation twin
-    of :func:`kernel_fault_hook`.  Under jit the dispatch runs at TRACE
-    time, so a warm cache hit never reaches the hook; what it times is the
-    dispatch/trace cost a forward actually pays (on CPU interpret mode
-    that includes execution).  :func:`repro.obs.profile.kernel_timer`
-    layers the metrics/trace recording on top.  Zero cost uninstalled:
-    one ``None`` check per dispatch."""
-    global _DISPATCH_HOOK
-    prev = _DISPATCH_HOOK
-    _DISPATCH_HOOK = fn
-    try:
-        yield
-    finally:
-        _DISPATCH_HOOK = prev
-
-
-def _dispatch(kind: str, fn, *args):
-    if _DISPATCH_HOOK is None:
-        return fn(*args)
-    t0 = time.perf_counter()
-    out = fn(*args)
-    _DISPATCH_HOOK(kind, time.perf_counter() - t0)
-    return out
 
 
 # ---------------------------------------------------------------------------
@@ -289,8 +253,7 @@ def bitmap_spmm(x: jax.Array, w: BitmapCompressed, bm: int | None = None,
         t_max = w.max_per_col
     fn = _jitted("bitmap", _bitmap_builder, w.k, _row_tile(x, bm),
                  max(int(t_max), 1), resolve_pipeline(pipeline), _interpret())
-    return _dispatch("bitmap", fn, x, w.blocks, w.counts, w.row_ids,
-                     w.offsets)
+    return fn(x, w.blocks, w.counts, w.row_ids, w.offsets)
 
 
 # ---------------------------------------------------------------------------
@@ -338,7 +301,7 @@ def nm_spmm(x: jax.Array, w: NMCompressed, bm: int | None = None,
     bk = tiling.lane_tile(w.k) if bk is None else bk
     fn = _jitted("nm", _nm_builder, w.n_sel, w.m_group, _row_tile(x, bm),
                  bn, bk, resolve_pipeline(pipeline), _interpret())
-    return _dispatch("nm", fn, x, w.values, w.indices)
+    return fn(x, w.values, w.indices)
 
 
 # ---------------------------------------------------------------------------
@@ -355,4 +318,4 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = True, bq: int = 128, bk: int = 128
                     ) -> jax.Array:
     fn = _jitted("flash", _flash_builder, causal, bq, bk, _interpret())
-    return _dispatch("flash", fn, q, k, v)
+    return fn(q, k, v)

@@ -128,6 +128,7 @@ def prefill_request(model, params, prompt: jax.Array, max_len: int,
         return lg[0], cache
 
 
+@jax.named_scope("slot_write")
 def write_slot(cache, row_cache, slot):
     """Write a batch-1 cache into batch row ``slot`` of a slotted cache.
 
@@ -271,25 +272,30 @@ class Mixer:
                           slot=slot):
                 self.cache = self._write_fn(self.cache, rcache,
                                             jnp.asarray(slot, jnp.int32))
-        report = HealthReport(gen=req.max_new, request_id=str(req.uid),
-                              trace_id=tid)
-        report.t_prefill_s = time.perf_counter() - t0
-        self.t_admit += report.t_prefill_s
-        omet.counter_inc("mixer_admissions_total")
-        omet.counter_inc("mixer_tokens_admitted_total", plen)
+            report = HealthReport(gen=req.max_new, request_id=str(req.uid),
+                                  trace_id=tid)
+            report.t_prefill_s = time.perf_counter() - t0
+            self.t_admit += report.t_prefill_s
+            omet.counter_inc("mixer_admissions_total")
+            omet.counter_inc("mixer_tokens_admitted_total", plen)
 
-        self.active[slot] = True
-        self._req[slot] = req
-        self._emitted[slot] = []
-        self.pos[slot] = plen
-        self._admit_step[slot] = self.step_count
-        self._t_admitted[slot] = time.perf_counter()
-        self._reports[slot] = report
-        self.events.append({"event": "admit", "uid": req.uid, "slot": slot,
-                            "step": self.step_count, "prompt_len": plen})
-        omet.gauge_set("mixer_slot_occupancy", int(self.active.sum()))
-        # the first token comes straight from prefill logits
-        self._emit(slot, sample_token(last, req, 0))
+            self.active[slot] = True
+            self._req[slot] = req
+            self._emitted[slot] = []
+            self.pos[slot] = plen
+            self._admit_step[slot] = self.step_count
+            self._t_admitted[slot] = time.perf_counter()
+            self._reports[slot] = report
+            self.events.append({"event": "admit", "uid": req.uid,
+                                "slot": slot, "step": self.step_count,
+                                "prompt_len": plen})
+            omet.gauge_set("mixer_slot_occupancy", int(self.active.sum()))
+            # the first token comes straight from prefill logits; reading
+            # it back is the admission's host sync
+            with otr.span("admit.first_token", trace_id=tid,
+                          request_id=req.uid):
+                tok = sample_token(last, req, 0)
+            self._emit(slot, tok)
         return slot
 
     # -- decode --------------------------------------------------------------
@@ -299,42 +305,46 @@ class Mixer:
         t0 = time.perf_counter()
         with otr.span("decode_step", step=self.step_count,
                       occupied=int(self.active.sum())):
-            toks = jnp.asarray(self.pending, jnp.int32)
-            pos = jnp.asarray(self.pos, jnp.int32)
-            logits, self.cache = self._step_fn(self.params, self.cache,
-                                               toks, pos)
-            greedy = np.asarray(jnp.argmax(logits, axis=-1))  # one host sync
-        self.step_count += 1
-        dt = time.perf_counter() - t0
-        omet.counter_inc("mixer_decode_steps_total")
-        omet.observe("mixer_decode_step_seconds", dt)
-        if self.straggler.observe(self.step_count, dt):
-            # timing-derived, hence stable=False: two runs of the same
-            # stream may legitimately spike at different steps
-            otr.event("straggler_spike", stable=False,
-                      step=self.step_count, dt_s=dt)
-            omet.counter_inc("mixer_straggler_spikes_total")
-        now = time.perf_counter()
-        for slot in np.nonzero(self.active)[0]:
-            slot = int(slot)
-            req = self._req[slot]
-            self.pos[slot] += 1
-            if self.deadline_s is not None and \
-                    now - self._t_admitted[slot] > self.deadline_s:
-                rep = self._reports[slot]
-                rep.deadline_hit = True
-                rep.record_fallback(
-                    "*", "deadline_exceeded",
-                    detail=f"{len(self._emitted[slot])}/{req.max_new} "
-                           f"tokens within {self.deadline_s}s")
-                self._evict(slot, "deadline")
-                continue
-            if req.temperature > 0.0:
-                tok = sample_token(logits[slot], req,
-                                   len(self._emitted[slot]))
-            else:
-                tok = int(greedy[slot])
-            self._emit(slot, tok)
+            with otr.span("decode_step.inputs"):
+                toks = jnp.asarray(self.pending, jnp.int32)
+                pos = jnp.asarray(self.pos, jnp.int32)
+            with otr.span("decode_step.dispatch"):
+                logits, self.cache = self._step_fn(self.params, self.cache,
+                                                   toks, pos)
+            with otr.span("decode_step.readback"):            # host sync
+                greedy = np.asarray(jnp.argmax(logits, axis=-1))
+            self.step_count += 1
+            dt = time.perf_counter() - t0
+            omet.counter_inc("mixer_decode_steps_total")
+            omet.observe("mixer_decode_step_seconds", dt)
+            if self.straggler.observe(self.step_count, dt):
+                # timing-derived, hence stable=False: two runs of the same
+                # stream may legitimately spike at different steps
+                otr.event("straggler_spike", stable=False,
+                          step=self.step_count, dt_s=dt)
+                omet.counter_inc("mixer_straggler_spikes_total")
+            now = time.perf_counter()
+            with otr.span("decode_step.emit"):
+                for slot in np.nonzero(self.active)[0]:
+                    slot = int(slot)
+                    req = self._req[slot]
+                    self.pos[slot] += 1
+                    if self.deadline_s is not None and \
+                            now - self._t_admitted[slot] > self.deadline_s:
+                        rep = self._reports[slot]
+                        rep.deadline_hit = True
+                        rep.record_fallback(
+                            "*", "deadline_exceeded",
+                            detail=f"{len(self._emitted[slot])}/{req.max_new} "
+                                   f"tokens within {self.deadline_s}s")
+                        self._evict(slot, "deadline")
+                        continue
+                    if req.temperature > 0.0:
+                        tok = sample_token(logits[slot], req,
+                                           len(self._emitted[slot]))
+                    else:
+                        tok = int(greedy[slot])
+                    self._emit(slot, tok)
         self.t_decode += time.perf_counter() - t0
 
     def _emit(self, slot: int, tok: int) -> None:

@@ -302,12 +302,9 @@ def main() -> None:
     if args.metrics is not None:
         registry = omet.MetricsRegistry()
         tel.enter_context(omet.collecting(registry))
-    if tracer is not None or registry is not None:
-        from repro.obs.profile import kernel_timer
-        tel.enter_context(kernel_timer(registry=registry, tracer=tracer))
-        if args.compressed:
-            from repro.exec import dispatch as exec_dispatch
-            exec_counters = tel.enter_context(exec_dispatch.instrument())
+    if (tracer is not None or registry is not None) and args.compressed:
+        from repro.exec import dispatch as exec_dispatch
+        exec_counters = tel.enter_context(exec_dispatch.instrument())
 
     def _telemetry_done(mx=None) -> None:
         """Close the capture contexts, fold the passive sources in, export."""

@@ -188,8 +188,9 @@ def attention_block(x: jax.Array, p: dict, cfg: ModelConfig,
     q = shard(q, "batch", None, "model", None)
     k = shard(k, "batch", None, "model", None)
     rep = nh // max(nk, 1)
-    kr, vr = _repeat_kv(k, rep), _repeat_kv(v, rep)
-    o = chunked_attention(q, kr, vr, causal=causal, window=window)
+    with jax.named_scope("attention"):
+        kr, vr = _repeat_kv(k, rep), _repeat_kv(v, rep)
+        o = chunked_attention(q, kr, vr, causal=causal, window=window)
     o = o.reshape(b, s, nh * hd)
     out = L.proj(o, p["wo"], "attn.wo")
     if return_kv:
@@ -222,29 +223,33 @@ def attention_decode_block(x: jax.Array, p: dict, cfg: ModelConfig,
     q = apply_rope(q.reshape(b, 1, nh, hd), pos1, freqs).reshape(b, nh, hd)
     k = apply_rope(k.reshape(b, 1, nk, hd), pos1, freqs).reshape(b, nk, hd)
     v = v.reshape(b, nk, hd)
-    if optflags.enabled("maskedkv") or cache_pos.ndim:
-        # one-hot masked blend: elementwise along the (possibly model-
-        # sharded) S axis — no replicate-and-repartition, unlike a dynamic
-        # update at a traced index.  Costs one cache-sized RMW pass.  A
-        # per-slot (B,) cache_pos always takes this path (each row writes
-        # at its own position — dynamic_update_slice cannot).
-        hot = (jnp.arange(k_cache.shape[1])[None, :] ==
-               jnp.reshape(cache_pos, (-1, 1)))[:, :, None, None]
-        k_cache = jnp.where(hot, k[:, None].astype(k_cache.dtype), k_cache)
-        v_cache = jnp.where(hot, v[:, None].astype(v_cache.dtype), v_cache)
-    else:
-        k_cache = jax.lax.dynamic_update_slice_in_dim(
-            k_cache, k[:, None].astype(k_cache.dtype), cache_pos, axis=1)
-        v_cache = jax.lax.dynamic_update_slice_in_dim(
-            v_cache, v[:, None].astype(v_cache.dtype), cache_pos, axis=1)
+    with jax.named_scope("kv_write"):
+        if optflags.enabled("maskedkv") or cache_pos.ndim:
+            # one-hot masked blend: elementwise along the (possibly model-
+            # sharded) S axis — no replicate-and-repartition, unlike a
+            # dynamic update at a traced index.  Costs one cache-sized RMW
+            # pass.  A per-slot (B,) cache_pos always takes this path (each
+            # row writes at its own position — dynamic_update_slice cannot).
+            hot = (jnp.arange(k_cache.shape[1])[None, :] ==
+                   jnp.reshape(cache_pos, (-1, 1)))[:, :, None, None]
+            k_cache = jnp.where(hot, k[:, None].astype(k_cache.dtype),
+                                k_cache)
+            v_cache = jnp.where(hot, v[:, None].astype(v_cache.dtype),
+                                v_cache)
+        else:
+            k_cache = jax.lax.dynamic_update_slice_in_dim(
+                k_cache, k[:, None].astype(k_cache.dtype), cache_pos, axis=1)
+            v_cache = jax.lax.dynamic_update_slice_in_dim(
+                v_cache, v[:, None].astype(v_cache.dtype), cache_pos, axis=1)
     s_max = k_cache.shape[1]
     length = jnp.minimum(pos + 1, s_max)
-    if optflags.enabled("gqagroup"):
-        o = decode_attention_gqa(q, k_cache, v_cache, length)
-    else:
-        rep = nh // max(nk, 1)
-        o = decode_attention(q, _repeat_kv(k_cache, rep),
-                             _repeat_kv(v_cache, rep), length)
+    with jax.named_scope("attention"):
+        if optflags.enabled("gqagroup"):
+            o = decode_attention_gqa(q, k_cache, v_cache, length)
+        else:
+            rep = nh // max(nk, 1)
+            o = decode_attention(q, _repeat_kv(k_cache, rep),
+                                 _repeat_kv(v_cache, rep), length)
     o = o.reshape(b, nh * hd)
     out = L.proj(o, p["wo"], "attn.wo")
     return out, k_cache, v_cache

@@ -57,12 +57,14 @@ def set_proj_hook(fn) -> None:
 
 def proj(x: jax.Array, w: jax.Array, role: str) -> jax.Array:
     """``x @ w`` over the last axis of ``x`` (the layers' projection shape:
-    w is (d_in, d_out)), dispatchable per ``role``."""
-    if _PROJ_HOOK is not None:
-        y = _PROJ_HOOK(x, w, role)
-        if y is not None:
-            return y
-    return jnp.einsum("...d,df->...f", x, w.astype(COMPUTE_DTYPE))
+    w is (d_in, d_out)), dispatchable per ``role``; its device ops carry
+    ``role`` as their name scope, dense or kernel-served."""
+    with jax.named_scope(role):
+        if _PROJ_HOOK is not None:
+            y = _PROJ_HOOK(x, w, role)
+            if y is not None:
+                return y
+        return jnp.einsum("...d,df->...f", x, w.astype(COMPUTE_DTYPE))
 
 
 # The hook's per-layer operand channel.  The transformer's scan runners
@@ -142,6 +144,7 @@ def unembed_loss(x: jax.Array, table: jax.Array, labels: jax.Array,
     return total / (b * n_chunks * chunk)
 
 
+@jax.named_scope("head")
 def logits_head(x: jax.Array, table: jax.Array) -> jax.Array:
     """Decode-time logits for the last position only: (B, V)."""
     logits = jnp.einsum("bd,vd->bv", x, table.astype(COMPUTE_DTYPE))

@@ -343,6 +343,7 @@ class Model:
         return L.unembed_loss(x, params["embed"], batch["labels"])
 
     # ---------------- decode ----------------
+    @jax.named_scope("prefill")
     def prefill(self, params: PyTree, tokens: jax.Array, max_len: int,
                 extras: PyTree = None) -> tuple[jax.Array, PyTree]:
         """Full-sequence forward that ALSO fills a fresh decode cache.
@@ -434,6 +435,7 @@ class Model:
             }
         return {"self": kv(cfg.n_layers, max_len)}
 
+    @jax.named_scope("decode")
     def decode_step(self, params: PyTree, cache: PyTree, tokens: jax.Array,
                     pos: jax.Array, extras: PyTree = None
                     ) -> tuple[jax.Array, PyTree]:

@@ -11,8 +11,9 @@ when off, deterministic where it must be:
     adapters over the five pre-existing measurement sources
     (``instrument()``, memo stats, kernel-cache stats, StragglerMonitor,
     HealthReport); JSON + Prometheus text exposition exports.
-  * :mod:`repro.obs.profile` — opt-in ``jax.profiler`` capture and a
-    per-kernel-dispatch timing hook.
+  * :mod:`repro.obs.profile` — opt-in ``jax.profiler`` capture, inside
+    which the spans above are ``TraceAnnotation`` events and the device ops
+    carry the program's name scopes.
 
 Surfaced by the serve CLI's ``--trace PATH`` / ``--metrics PATH`` flags
 and measured by ``bench_serve``'s ``serve_telemetry_overhead`` row.
@@ -22,7 +23,7 @@ from repro.obs.metrics import (MetricsRegistry, collect_caches, collecting,
                                current_metrics, ingest_health,
                                ingest_instrument, ingest_kernel_cache,
                                ingest_memo_stats, ingest_straggler)
-from repro.obs.profile import jax_trace, kernel_timer
+from repro.obs.profile import jax_trace
 from repro.obs.trace import (Tracer, current_tracer, event, span, trace_id,
                              tracing)
 
@@ -30,6 +31,6 @@ __all__ = [
     "MetricsRegistry", "Tracer",
     "collect_caches", "collecting", "current_metrics", "current_tracer",
     "event", "ingest_health", "ingest_instrument", "ingest_kernel_cache",
-    "ingest_memo_stats", "ingest_straggler", "jax_trace", "kernel_timer",
-    "span", "trace_id", "tracing",
+    "ingest_memo_stats", "ingest_straggler", "jax_trace", "span",
+    "trace_id", "tracing",
 ]

@@ -3,16 +3,25 @@
 One ambient :class:`Tracer` (installed with :func:`tracing`) collects an
 ordered stream of span begin/end marks and instant events.  The module
 functions :func:`span` / :func:`event` are the instrumentation surface the
-rest of the codebase calls: with no tracer installed they resolve to a
-shared no-op (one ``None`` check — the serving hot loops pay nothing, and
-the off path's tokens are bit-identical, pinned by ``tests/test_obs.py``).
+rest of the codebase calls: with no tracer installed and no profiler
+recording they resolve to a shared no-op (one ``None`` check and one
+``TraceAnnotation.is_enabled()`` call — the serving hot loops pay next to
+nothing, and the off path's tokens are bit-identical, pinned by
+``tests/test_obs.py``).
+
+Spans are also put on the profiler's clock: while a ``jax.profiler``
+session records (``jax.profiler.start_trace``, :func:`repro.obs.profile
+.jax_trace`), every :func:`span` opens a ``jax.profiler.TraceAnnotation``
+of the same name, its args as the event's stats, so the host phases of a
+request sit on the same timeline as the device work they cause.  Whether
+a profiler records decides it; there is no flag.  Instant events stay
+tracer-only.
 
 Two exports, two purposes:
 
   * :meth:`Tracer.chrome_trace` — the Chrome trace-event JSON dialect
     (load the saved file in ``chrome://tracing`` or Perfetto): ``B``/``E``
-    span pairs, ``i`` instants, ``X`` complete events (used by the
-    kernel-dispatch timing hook), microsecond timestamps relative to the
+    span pairs and ``i`` instants, microsecond timestamps relative to the
     tracer's epoch.
   * :meth:`Tracer.stable_trace` — the deterministic projection: timings
     dropped, ordering and args kept, timing-derived events (recorded with
@@ -36,16 +45,17 @@ import json
 import time
 from typing import Any, Iterator, Optional
 
+from jax.profiler import TraceAnnotation
+
 
 class Tracer:
     """Ordered in-memory trace collector.
 
     ``events`` is the raw record stream: dicts with ``ph`` (``"B"`` begin
-    span / ``"E"`` end span / ``"i"`` instant / ``"X"`` complete),
-    ``name``, ``ts`` (seconds since the tracer's epoch), ``args`` and —
-    for ``"X"`` — ``dur``.  Span begin/end must nest strictly (LIFO);
-    a mismatched :meth:`end` raises instead of silently corrupting the
-    stream."""
+    span / ``"E"`` end span / ``"i"`` instant), ``name``, ``ts`` (seconds
+    since the tracer's epoch) and ``args``.  Span begin/end must nest
+    strictly (LIFO); a mismatched :meth:`end` raises instead of silently
+    corrupting the stream."""
 
     def __init__(self) -> None:
         self._t0 = time.perf_counter()
@@ -79,16 +89,6 @@ class Tracer:
             ev["stable"] = False
         self.events.append(ev)
 
-    def complete(self, name: str, dur_s: float,
-                 args: Optional[dict] = None, stable: bool = True) -> None:
-        """Record an already-finished region ending now (``dur_s`` long) —
-        the shape hook-based timers produce (kernel dispatch)."""
-        ev = {"ph": "X", "name": name, "ts": max(self._now() - dur_s, 0.0),
-              "dur": dur_s, "args": dict(args or {})}
-        if not stable:
-            ev["stable"] = False
-        self.events.append(ev)
-
     @property
     def depth(self) -> int:
         """Current span nesting depth (0 outside every span)."""
@@ -107,8 +107,6 @@ class Tracer:
             row: dict[str, Any] = {"name": ev["name"], "ph": ev["ph"],
                                    "ts": round(ev["ts"] * 1e6, 3),
                                    "pid": 0, "tid": 0}
-            if ev["ph"] == "X":
-                row["dur"] = round(ev["dur"] * 1e6, 3)
             if ev["ph"] == "i":
                 row["s"] = "t"
             if ev["args"]:
@@ -171,31 +169,45 @@ _NULL = _Null()
 
 
 class _Span:
-    __slots__ = ("_t", "_name")
+    """A span open in the ambient tracer, the profiler, or both."""
 
-    def __init__(self, tracer: Tracer, name: str, args: dict) -> None:
+    __slots__ = ("_t", "_name", "_ann")
+
+    def __init__(self, tracer: Optional[Tracer], name: str, args: dict,
+                 recording: bool) -> None:
         self._t = tracer
         self._name = name
-        tracer.begin(name, args)
+        self._ann = None
+        if recording:
+            self._ann = TraceAnnotation(
+                name, **{k: v for k, v in args.items() if v is not None})
+            self._ann.__enter__()
+        if tracer is not None:
+            tracer.begin(name, args)
 
     def __enter__(self):
         return self
 
     def __exit__(self, *exc):
-        self._t.end(self._name)
+        if self._t is not None:
+            self._t.end(self._name)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         return False
 
 
 def span(name: str, **args):
-    """Context manager marking a span; a shared no-op when tracing is off.
+    """Context manager marking a span; a shared no-op when no tracer is
+    installed and no profiler records.
 
     The span BEGINS at the call (not at ``__enter__``), so exceptions
     between construction and entry still nest correctly in practice —
     always use it as ``with span(...):``."""
     t = _TRACER
-    if t is None:
+    recording = TraceAnnotation.is_enabled()
+    if t is None and not recording:
         return _NULL
-    return _Span(t, name, args)
+    return _Span(t, name, args, recording)
 
 
 def event(name: str, stable: bool = True, **args) -> None:

@@ -24,7 +24,11 @@ Contracts pinned here:
   * throughput reports survive ~0-second phases (``_rate`` denominator
     floor) — the pre-fix CLI divided by raw wall-clock;
   * an all-equal position VECTOR decodes bit-identically to the scalar
-    position (the mixer's decode primitive degenerates to lockstep).
+    position (the mixer's decode primitive degenerates to lockstep);
+  * under a ``jax.profiler`` capture the mixer's host phases are spans on
+    the profile's host plane: ``decode_step.{inputs,dispatch,readback,
+    emit}`` in order inside ``decode_step``, ``admit.first_token`` inside
+    ``admit`` — and the same phases, in the same order, in a tracer.
 """
 
 import jax
@@ -350,3 +354,61 @@ def test_decode_step_rejects_bad_pos_shape(fp32_compute):
     with pytest.raises(ValueError, match="scalar or a per-slot vector"):
         model.decode_step(params, cache, tok, jnp.asarray([0, 0, 0],
                                                           jnp.int32))
+
+
+# ---------------------------------------------------------------------------
+# host phases on the profiler's clock
+# ---------------------------------------------------------------------------
+
+STEP_PHASES = ["decode_step.inputs", "decode_step.dispatch",
+               "decode_step.readback", "decode_step.emit"]
+
+
+def _nested(spans, parent, children):
+    """Each ``parent`` span holds one of each child, in order, and no
+    child lies outside a parent."""
+    parents = [(a, b) for n, a, b in spans if n == parent]
+    kids = sorted((a, b, n) for n, a, b in spans if n in children)
+    assert parents and len(kids) == len(parents) * len(children)
+    for a, b in parents:
+        inside = [(ka, kb, n) for ka, kb, n in kids if a <= ka and kb <= b]
+        assert [n for _, _, n in inside] == children
+        ends = [a] + [x for ka, kb, _ in inside for x in (ka, kb)] + [b]
+        assert ends == sorted(ends)
+
+
+def test_traced_mixer_phases_nest_on_the_profile_host_plane(tmp_path):
+    import glob
+    from jax.profiler import ProfileData
+    from repro.obs import trace as otr
+    cfg, model, params = _dense()
+    mx = Mixer(model, params, slots=2, max_len=16)
+    reqs = _stream(cfg, [3, 4], 4)
+    mx.admit(reqs[0])
+    mx._step()                                  # compiled before the capture
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        mx.admit(reqs[1])
+        for _ in range(2):
+            mx._step()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)
+    names = {"decode_step", "admit", "admit.first_token", *STEP_PHASES}
+    spans = [(ev.name, ev.start_ns, ev.end_ns)
+             for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for ev in line.events
+             if ev.name in names]
+    assert sum(n == "decode_step" for n, _, _ in spans) == 2
+    _nested(spans, "decode_step", STEP_PHASES)
+    _nested(spans, "admit", ["admit.first_token"])
+
+    tracer = otr.Tracer()
+    with otr.tracing(tracer):
+        mx._step()
+    order = [(e["ph"], e["name"]) for e in tracer.events if e["ph"] in "BE"]
+    assert order == [("B", "decode_step")] + [
+        (ph, n) for n in STEP_PHASES for ph in "BE"] + [("E", "decode_step")]

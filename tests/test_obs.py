@@ -7,8 +7,8 @@ Contracts pinned here:
     surface is a shared no-op (``span`` returns the same ``_NULL``
     object every call; ``trace_id`` is None);
   * :meth:`Tracer.chrome_trace` is valid Chrome trace-event JSON —
-    ``B``/``E`` balanced, instants carry ``s``, ``X`` events carry
-    ``dur``, every row JSON-serializable;
+    ``B``/``E`` balanced, instants carry ``s``, every row
+    JSON-serializable;
   * :meth:`Tracer.stable_trace` drops every timing field, keeps order
     and args, and excludes ``stable=False`` (timing-derived) events —
     two runs of the same seeded mixer stream (greedy AND sampled)
@@ -28,8 +28,11 @@ Contracts pinned here:
   * a traced guarded run over a bit-flipped store emits the ``demote``
     event and the matching ``serve_verify_failures_total`` /
     ``serve_fallbacks_total`` counters;
-  * :func:`kernel_timer` records sparse-kernel dispatches (trace-time)
-    into both planes, as unstable ``X`` events.
+  * while a ``jax.profiler`` session records, ``span`` is also a
+    ``TraceAnnotation`` of the same name on the profile's host plane (and
+    only then); the compiled serving steps carry the program's name scopes
+    (``decode``, ``prefill``, ``slot_write``, ``attention``, ``kv_write``,
+    ``head``, each projection role) and each Pallas kernel its own name.
 """
 
 import json
@@ -50,7 +53,6 @@ from repro.launch.mixer import Mixer, Request
 from repro.models.transformer import Model
 from repro.obs import metrics as omet
 from repro.obs import trace as otr
-from repro.obs.profile import kernel_timer
 from repro.runtime import inject
 from repro.runtime.guard import HealthReport, guarded_generate
 
@@ -135,20 +137,17 @@ def test_chrome_trace_schema_valid():
     with otr.tracing(tr):
         with otr.span("phase", batch=2):
             otr.event("mark", pos=3)
-        tr.complete("kernel:bitmap", 0.001, {"kind": "bitmap"},
-                    stable=False)
     doc = tr.chrome_trace()
     json.loads(json.dumps(doc))               # fully serializable
     assert doc["displayTimeUnit"] == "ms"
     rows = doc["traceEvents"]
-    assert [r["ph"] for r in rows] == ["B", "i", "E", "X"]
+    assert [r["ph"] for r in rows] == ["B", "i", "E"]
     for r in rows:
         assert set(r) >= {"name", "ph", "ts", "pid", "tid"}
         assert r["ts"] >= 0.0
     assert sum(r["ph"] == "B" for r in rows) == \
         sum(r["ph"] == "E" for r in rows)
     assert rows[1]["s"] == "t"                # instants carry scope
-    assert rows[3]["dur"] >= 0.0              # X events carry duration
 
 
 def test_stable_trace_drops_timings_and_unstable_events(tmp_path):
@@ -391,7 +390,7 @@ def test_mixer_straggler_lands_in_stats_and_snapshot(dense):
 
 
 # ---------------------------------------------------------------------------
-# serving integration: off-switch + guarded path + kernel timer
+# serving integration: off-switch + guarded path
 # ---------------------------------------------------------------------------
 
 def test_telemetry_off_and_on_leave_tokens_bit_identical(dense):
@@ -400,8 +399,7 @@ def test_telemetry_off_and_on_leave_tokens_bit_identical(dense):
                           jnp.int32)
     toks_off, _, _ = serve.generate(model, params, prompts, 3, 12)
     with otr.tracing(otr.Tracer()) as tr, \
-            omet.collecting(omet.MetricsRegistry()) as reg, \
-            kernel_timer(registry=reg, tracer=tr):
+            omet.collecting(omet.MetricsRegistry()) as reg:
         toks_on, _, _ = serve.generate(model, params, prompts, 3, 12)
     toks_off2, _, _ = serve.generate(model, params, prompts, 3, 12)
     np.testing.assert_array_equal(np.asarray(toks_off), np.asarray(toks_on))
@@ -440,21 +438,113 @@ def test_guarded_traced_run_emits_demote_and_matching_counters(serving):
     assert reg.value("serve_tokens_generated_total") == report.steps
 
 
-def test_kernel_timer_records_dispatches(serving):
+# ---------------------------------------------------------------------------
+# the profiler's clock: spans as TraceAnnotations, device work by scope
+# ---------------------------------------------------------------------------
+
+def _host_events(log_dir) -> list:
+    """(name, start_ns, end_ns, stats) of every host-plane event in the
+    one profile written under ``log_dir``."""
+    import glob
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(f"{log_dir}/**/*.xplane.pb", recursive=True)
+    return [(ev.name, ev.start_ns, ev.end_ns, dict(ev.stats))
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events]
+
+
+def _capture(log_dir, fn):
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(log_dir), profiler_options=opts)
+    try:
+        return fn()
+    finally:
+        jax.profiler.stop_trace()
+
+
+def test_span_is_a_trace_annotation_only_while_a_profiler_records(tmp_path):
+    assert otr.current_tracer() is None
+    assert otr.span("before", k=1) is otr.span("other")   # shared no-op
+
+    def traced():
+        s = otr.span("profiled", step=3, trace_id=None)
+        assert s is not otr.span("other")
+        with s:
+            with otr.span("profiled.child"):
+                pass
+        tracer = otr.Tracer()
+        with otr.tracing(tracer), otr.span("both", x=2):
+            pass
+        return tracer
+    tracer = _capture(tmp_path, traced)
+    assert otr.span("after") is otr.span("other")
+    with otr.span("after"):
+        pass
+    ev = {name: (a, b, st) for name, a, b, st in _host_events(tmp_path)}
+    assert "before" not in ev and "after" not in ev
+    assert ev["profiled"][2] == {"step": 3}     # args as stats, None dropped
+    a, b, _ = ev["profiled"]
+    ca, cb, _ = ev["profiled.child"]
+    assert a <= ca <= cb <= b
+    assert ev["both"][2] == {"x": 2}           # profiler and tracer alike
+    assert [e["name"] for e in tracer.events] == ["both", "both"]
+
+
+def _scope_parts(text: str) -> set:
+    return {part for name in re.findall(r'op_name="([^"]*)"', text)
+            for part in name.split("/")}
+
+
+def test_compiled_decode_step_carries_the_program_scopes(serving):
+    """The name scopes reach the compiled decode step's ``op_name``
+    metadata, which the profiler reports each device op under."""
     cfg, model, plan, pruned, store = serving
     cm = rexec.CompressedModel(model, store)
-    tokens = jnp.asarray(np.arange(2 * 8).reshape(2, 8) % cfg.vocab,
-                         jnp.int32)
-    reg = omet.MetricsRegistry()
-    tracer = otr.Tracer()
-    with kernel_timer(registry=reg, tracer=tracer):
-        # a FRESH jit object forces a trace, which is where dispatch runs
-        jax.jit(cm.hidden_states)(pruned, tokens)
-    assert reg.total("kernel_dispatch_total") > 0
-    assert reg.value("kernel_dispatch_total", kind="bitmap") > 0
-    snap = reg.snapshot()
-    assert any(k.startswith("kernel_dispatch_seconds")
-               for k in snap["histograms"])
-    xs = [e for e in tracer.events if e["ph"] == "X"]
-    assert xs and all(e["name"].startswith("kernel:") for e in xs)
-    assert not tracer.stable_trace()          # all timing-derived
+    cache = cm.init_cache(2, 16)
+    lowered = jax.jit(cm.decode_step).lower(
+        pruned, cache, jnp.zeros(2, jnp.int32), jnp.zeros(2, jnp.int32))
+    parts = _scope_parts(lowered.compile().as_text())
+    roles = {op.role for op in plan.ops}
+    assert {"decode", "attention", "kv_write", "head"} <= parts
+    assert roles <= parts and "ffn.w_down" in roles
+    assert parts & {"bitmap_spmm", "bitmap_spmm_pipelined"}
+
+
+def test_compiled_prefill_and_slot_write_carry_their_scopes(serving):
+    from repro.launch.mixer import write_slot
+    cfg, model, plan, pruned, store = serving
+    cm = rexec.CompressedModel(model, store)
+    tokens = jnp.zeros((1, 8), jnp.int32)
+    prefill = jax.jit(cm.prefill, static_argnums=2).lower(pruned, tokens, 16)
+    parts = _scope_parts(prefill.compile().as_text())
+    assert {"prefill", "attention"} <= parts
+    assert {op.role for op in plan.ops} <= parts
+    cache, row = cm.init_cache(2, 16), cm.init_cache(1, 16)
+    write = jax.jit(write_slot).lower(cache, row, jnp.asarray(1, jnp.int32))
+    assert "slot_write" in _scope_parts(write.compile().as_text())
+
+
+@pytest.mark.parametrize("kernel", ["bitmap_spmm", "bitmap_spmm_pipelined",
+                                    "nm_spmm", "nm_spmm_pipelined",
+                                    "flash_attention"])
+def test_each_pallas_kernel_is_named_after_itself(kernel):
+    from repro.kernels import ops as kops
+    rng = np.random.default_rng(0)
+    w = rng.standard_normal((256, 256)).astype(np.float32)
+    x = jnp.asarray(rng.standard_normal((8, 256)), jnp.float32)
+    if kernel.startswith("bitmap"):
+        w[:128, :128] = 0.0
+        wc = kops.compress_bitmap(w, bn=128, bk=128)
+        pipeline = kernel.endswith("pipelined")
+        fn = lambda x: kops.bitmap_spmm(x, wc, pipeline=pipeline)  # noqa: E731
+    elif kernel.startswith("nm"):
+        wc = kops.compress_nm(w)
+        pipeline = kernel.endswith("pipelined")
+        fn = lambda x: kops.nm_spmm(x, wc, pipeline=pipeline)  # noqa: E731
+    else:
+        q = jnp.asarray(rng.standard_normal((2, 128, 64)), jnp.float32)
+        fn = lambda x: kops.flash_attention(q, q, q)  # noqa: E731
+    text = jax.jit(fn).lower(x).as_text(dialect="hlo", debug_info=True)
+    assert kernel in _scope_parts(text)
