@@ -40,6 +40,7 @@ from repro.configs.base import ModelConfig, ShapeCfg
 from repro.launch import hlo_cost
 from repro.launch.mesh import (axis_map_for, data_axes_of,
                                make_production_mesh, mesh_axis_sizes)
+from repro.models import optflags
 from repro.models.partition import batch_specs, cache_specs, param_specs
 from repro.models.sharding import logical_axis_rules
 from repro.models.transformer import Model, input_specs
@@ -96,7 +97,6 @@ def build_cell(cfg: ModelConfig, shape: ShapeCfg, mesh):
                                     _named(b_specs, mesh))
 
     # decode
-    from repro.models import optflags
     if optflags.enabled("bf16params"):
         params_abs = jax.tree.map(
             lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16)
@@ -117,7 +117,6 @@ def build_cell(cfg: ModelConfig, shape: ShapeCfg, mesh):
 def run_cell(arch: str, shape_name: str, multi_pod: bool,
              artifact_dir: str = ARTIFACT_DIR,
              opts: tuple[str, ...] = ()) -> dict:
-    from repro.models import optflags
     cfg = get_config(arch)
     shape = SHAPES[shape_name]
     mesh_name = "multi" if multi_pod else "single"
@@ -197,8 +196,8 @@ def main() -> None:
     ap.add_argument("--skip-existing", action="store_true")
     ap.add_argument("--out", default=ARTIFACT_DIR)
     ap.add_argument("--opts", default="",
-                    help="comma-separated optflags (padheads,replkv,"
-                         "saveremat,maskedkv,sparseffn); artifacts get an "
+                    help="comma-separated optflags ("
+                         f"{','.join(optflags.ALL_FLAGS)}); artifacts get an "
                          "__opt-<flags> suffix")
     args = ap.parse_args()
 

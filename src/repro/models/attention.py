@@ -110,45 +110,32 @@ def _valid_mask(s: int, length: jax.Array) -> jax.Array:
     return jnp.arange(s)[None, :] < jnp.reshape(length, (-1, 1))
 
 
-def decode_attention_gqa(q: jax.Array, k_cache: jax.Array,
-                         v_cache: jax.Array, length: jax.Array) -> jax.Array:
-    """Grouped-query decode attention WITHOUT materializing repeated KV.
+def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
+                     length: jax.Array) -> jax.Array:
+    """One-token attention against a cache, one pass over each KV group.
 
-    q: (B, H, D); caches: (B, S, Hkv, D) with H = r·Hkv.  ``length`` is the
-    number of valid cache positions — a scalar, or (B,) per-slot lengths.
-    The cache is consumed in its stored layout (S may be model-sharded: the
-    only cross-shard values are the (B, Hkv, r)-sized softmax stats and the
-    (B, Hkv, r, D) output partials — never the cache itself)."""
+    q: (B, H, D); caches: (B, S, Hkv, D) with H = r·Hkv (r = 1 is MHA);
+    ``length``: number of valid cache positions — a scalar, or (B,)
+    per-slot lengths for mixed-position batches.  The query is grouped
+    to (B, Hkv, r, D) and contracted against the cache in its stored
+    layout, so no (B, S, H, D) copy of the cache is built; with S
+    model-sharded only the (B, Hkv, r)-sized softmax statistics and
+    output partials cross shards, never the cache.  Cost is linear in
+    S — this is the decode_32k / long_500k step.
+    """
     b, s, hk, d = k_cache.shape
     h = q.shape[1]
+    if h % hk:
+        raise ValueError(f"{h} query heads do not group over {hk} KV heads")
     r = h // hk
     qg = q.reshape(b, hk, r, d)
     scale = 1.0 / math.sqrt(d)
     scores = jnp.einsum("bgrd,bsgd->bgrs", qg, k_cache) * scale
-    valid = _valid_mask(s, length)[:, None, None, :]
+    valid = _valid_mask(s, length)[:, None, None, :]    # (1 | B, 1, 1, S)
     scores = jnp.where(valid, scores, NEG_INF)
     w = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
     out = jnp.einsum("bgrs,bsgd->bgrd", w.astype(COMPUTE_DTYPE), v_cache)
     return out.reshape(b, h, d).astype(q.dtype)
-
-
-def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
-                     length: jax.Array) -> jax.Array:
-    """One-token attention against a cache.
-
-    q: (B, H, D); caches: (B, S, H, D); ``length``: number of valid cache
-    positions — a scalar, or (B,) per-slot lengths for mixed-position
-    batches.  Cost is linear in S — this is the decode_32k / long_500k
-    step.
-    """
-    b, s, h, d = k_cache.shape
-    scale = 1.0 / math.sqrt(d)
-    valid = _valid_mask(s, length)                       # (1 | B, S)
-    scores = jnp.einsum("bhd,bshd->bhs", q, k_cache) * scale
-    scores = jnp.where(valid[:, None, :], scores, NEG_INF)
-    w = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
-    out = jnp.einsum("bhs,bshd->bhd", w.astype(COMPUTE_DTYPE), v_cache)
-    return out.astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -244,12 +231,7 @@ def attention_decode_block(x: jax.Array, p: dict, cfg: ModelConfig,
     s_max = k_cache.shape[1]
     length = jnp.minimum(pos + 1, s_max)
     with jax.named_scope("attention"):
-        if optflags.enabled("gqagroup"):
-            o = decode_attention_gqa(q, k_cache, v_cache, length)
-        else:
-            rep = nh // max(nk, 1)
-            o = decode_attention(q, _repeat_kv(k_cache, rep),
-                                 _repeat_kv(v_cache, rep), length)
+        o = decode_attention(q, k_cache, v_cache, length)
     o = o.reshape(b, nh * hd)
     out = L.proj(o, p["wo"], "attn.wo")
     return out, k_cache, v_cache

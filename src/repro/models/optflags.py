@@ -36,13 +36,9 @@ import threading
 _state = threading.local()
 
 ALL_FLAGS = ("padheads", "replkv", "saveremat", "maskedkv", "sparseffn",
-             "seqpar", "gqagroup", "bf16params")
+             "seqpar", "bf16params")
 # bf16params — serve with bf16 parameters (cast once at load): decode is a
 #              weight-stream problem; fp32 master copies belong to training.
-# gqagroup — decode attention computes per KV-head GROUP (no materialized
-#            _repeat_kv broadcast of the cache): the S-sharded cache is
-#            consumed in place; softmax/contraction collectives shrink to
-#            (B, Hkv, rep)-sized scalars instead of cache-sized gathers.
 
 
 def active() -> frozenset:

@@ -572,13 +572,10 @@ class Model:
                     hn, p["attn"], cfg, freqs, pos, kk, vv, pos)
                 h = h + y
                 hn = L.rms_norm(h[:, None], p["ln3"], cfg.norm_eps)[:, 0]
-                rep = cfg.n_heads // max(cfg.n_kv_heads, 1)
                 q = jnp.einsum("bd,de->be", hn,
                                p["cross"]["wq"].astype(L.COMPUTE_DTYPE))
                 q = q.reshape(-1, cfg.n_heads, cfg.head_dim)
-                y = attn.decode_attention(
-                    q, attn._repeat_kv(ck, rep), attn._repeat_kv(cv, rep),
-                    ck.shape[1])
+                y = attn.decode_attention(q, ck, cv, ck.shape[1])
                 h = h + jnp.einsum(
                     "be,ed->bd", y.reshape(y.shape[0], -1),
                     p["cross"]["wo"].astype(L.COMPUTE_DTYPE))

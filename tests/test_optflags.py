@@ -1,5 +1,5 @@
 """Optimization flags must preserve semantics (hillclimb changes are
-perf-only): decode equivalence under gqagroup/maskedkv, padheads smoke,
+perf-only): decode equivalence under maskedkv, padheads smoke,
 sparse FFN path, HLO cost analyzer sanity."""
 
 import jax
@@ -26,7 +26,7 @@ def _decode_logits(cfg, flags, steps=6):
     return jnp.stack(outs)
 
 
-@pytest.mark.parametrize("flag", ["gqagroup", "maskedkv"])
+@pytest.mark.parametrize("flag", ["maskedkv"])
 def test_decode_flags_preserve_logits(flag):
     cfg = get_config("deepseek-coder-33b").reduced()
     base = _decode_logits(cfg, ())
