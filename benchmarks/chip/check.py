@@ -2,16 +2,18 @@
 
 After the window, a sample of the requests it finished, drawn from the
 seed, always holding the longest and spread over the slots, is run through
-the float32 reference (``reference/gqa.py``) over each prompt followed by
-its served tokens.  At every served position the reference's best logit is
-compared with its logit of the token the server chose; the number compared
-is the widest such gap, in logits.  Greedy decoding that matched the
-reference reads about the rounding of the served arithmetic; a wrong token,
-position, cache row or weight reads the spread of the logits themselves.
+the float32 reference of the configuration's architecture (its module's
+``logits``) over each prompt followed by its served tokens.  At every
+served position the reference's best logit is compared with its logit of
+the token the server chose; the number compared is the widest such gap,
+in logits.  Greedy decoding that matched the reference reads about the
+rounding of the served arithmetic; a wrong token, position, cache row or
+weight reads the spread of the logits themselves.
 
-``controls`` also reads each named control (``gqa.CONTROL_DTYPES``): the
-reference again at a lower precision, taking at each position of the same
-prompts and tokens the token that it puts first.
+``controls`` also reads each named control (the architecture's
+``CONTROL_DTYPES``): the reference again at a lower precision, taking at
+each position of the same prompts and tokens the token that it puts
+first.
 """
 
 from __future__ import annotations
@@ -74,14 +76,14 @@ def _padded(rows: np.ndarray, bucket: int) -> np.ndarray:
     return np.concatenate([rows, np.full(-len(rows) % bucket, rows[0])])
 
 
-def gaps(w: dict, samples: list[tuple], dims: dict, max_len: int,
+def gaps(logits, w: dict, samples: list[tuple], dims: dict, max_len: int,
          controls=()) -> dict:
     """Widest gap below the reference's best logit of the served tokens
     (``logit_gap``) and of each control's picks (``controls``: name → gap),
     over every position of ``samples``; the tokens and slots compared.
-    Every sequence is padded to ``max_len``, so that one compiled
-    reference serves them all."""
-    from reference import gqa
+    ``logits(w, tokens, rows, dims, quant=..., bucket=...)`` is the
+    reference.  Every sequence is padded to ``max_len``, so that one
+    compiled reference serves them all."""
     out = {"logit_gap": 0.0, "tokens": 0,
            "slots": len({slot for slot, _, _ in samples}),
            "controls": {name: 0.0 for name in controls}}
@@ -89,14 +91,14 @@ def gaps(w: dict, samples: list[tuple], dims: dict, max_len: int,
         n, plen = len(toks), len(prompt)
         seq = np.concatenate([prompt, toks[:-1]]).astype(np.int32)
         rows = _padded(np.arange(plen - 1, plen - 1 + n), max_len)
-        ref = np.asarray(gqa.logits(w, seq, rows, dims, bucket=max_len))[:n]
+        ref = np.asarray(logits(w, seq, rows, dims, bucket=max_len))[:n]
         best = ref.max(-1)
         out["logit_gap"] = max(out["logit_gap"], float(
             (best - ref[np.arange(n), toks]).max()))
         out["tokens"] += n
         for name in controls:
-            ctl = np.asarray(gqa.logits(w, seq, rows, dims, quant=name,
-                                        bucket=max_len))[:n]
+            ctl = np.asarray(logits(w, seq, rows, dims, quant=name,
+                                    bucket=max_len))[:n]
             pick = ctl.argmax(-1)
             out["controls"][name] = max(out["controls"][name], float(
                 (best - ref[np.arange(n), pick]).max()))

@@ -2,14 +2,15 @@
 
 Everything here is computed from shapes and dtypes (of the served arrays,
 or of the configuration), never measured, so that a roofline share or a
-utilization divides a fixed amount of work by a measured time.
+utilization divides a fixed amount of work by a measured time.  What
+depends on the architecture (the FLOPs a decoded token needs, the weight
+bytes a step reads) is its module's (``arch/<name>.py``); what is here
+serves every one.
 """
 
 from __future__ import annotations
 
 import dataclasses
-
-import numpy as np
 
 #: Published peaks of one chip, keyed by ``jax.Device.device_kind``.
 #: Source: Google Cloud documentation, "TPU v5e" (system architecture):
@@ -82,36 +83,6 @@ def served_kernel_roles(stacked, nnzb: dict[str, int]) -> list[KernelRole]:
                               payload_itemsize=d["blocks"].dtype.itemsize,
                               meta_bytes=int(meta)))
     return out
-
-
-def decode_token_flops(cfg: dict, nnz_per_layer: int, ctx: np.ndarray
-                       ) -> float:
-    """FLOPs that decoding tokens at context lengths ``ctx`` requires,
-    whatever serves them: 2 × the non-zero projection weights of every
-    layer, the tied head (2 · vocab · d_model), and attention over the live
-    context (QKᵀ and PV: 4 · heads · head_dim · ctx per layer)."""
-    ctx = np.asarray(ctx, np.float64)
-    per_token = 2.0 * cfg["n_layers"] * nnz_per_layer \
-        + 2.0 * cfg["vocab"] * cfg["d_model"]
-    attn = 4.0 * cfg["n_layers"] * cfg["n_heads"] * cfg["head_dim"]
-    return float(per_token * ctx.size + attn * ctx.sum())
-
-
-def step_weight_bytes(kernel_roles: list[KernelRole], stacked, params,
-                      dims: dict) -> float:
-    """Bytes of served weights one decode step must read: the non-zero
-    payload and metadata of every kernel-served role in every layer, the
-    whole array of every role served dense, and the tied head."""
-    layers = dims["n_layers"]
-    out = sum(layers * bitmap_call_cost(r, m=0, x_itemsize=0)[1]
-              for r in kernel_roles)
-    kernel = {r.role for r in kernel_roles}
-    for role, sr in stacked.roles.items():
-        if role not in kernel:
-            group, leaf = role.split(".", 1)
-            w = params["blocks"]["attn" if group == "attn" else "ffn"][leaf]
-            out += w.size * w.dtype.itemsize
-    return float(out + params["embed"].size * params["embed"].dtype.itemsize)
 
 
 def kv_bytes_per_position(cache) -> float:

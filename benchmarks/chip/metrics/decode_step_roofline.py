@@ -1,10 +1,11 @@
 """The decode step's share of its roofline, %: the least time the traced
-steps could take over their busy device time.  Each step must read every
-served weight once (the non-zero payload and metadata of kernel-served
-roles, dense roles whole, the tied head) and the live KV context of each
-slot, and do the FLOPs its tokens require (``counts.decode_token_flops``);
-its least time is the larger of bytes over HBM bandwidth and FLOPs over
-the bf16 peak, all counted from the served arrays' shapes and dtypes."""
+steps could take over their busy device time.  Each step must read the
+served weights it touches once (``ctx["weight_bytes"]``) and the KV its
+tokens attend to (``ctx["decode_kv_bytes"]``), and do the FLOPs its tokens
+require (``ctx["decode_flops"]``), each as the configuration's
+architecture counts it from the served arrays' shapes and dtypes; its
+least time is the larger of bytes over HBM bandwidth and FLOPs over the
+bf16 peak."""
 
 import counts
 
@@ -17,8 +18,6 @@ def read(ctx):
     n = len(tr.steps)
     # the window's tokens spread over its steps, scaled to the traced ones
     share = n / sum(1 for _, b, _ in rec.steps if rec.t0 <= b <= rec.t_end)
-    flops = counts.decode_token_flops(ctx["dims"], ctx["nnz_layer"],
-                                      rec.ctx) * share
-    nbytes = n * ctx["weight_bytes"] \
-        + ctx["kv_bytes_per_position"] * sum(rec.ctx) * share
+    flops = ctx["decode_flops"] * share
+    nbytes = n * ctx["weight_bytes"] + ctx["decode_kv_bytes"] * share
     return 100.0 * counts.least_time_s(flops, nbytes, ctx["peak"]) / busy_s

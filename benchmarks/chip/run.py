@@ -5,7 +5,8 @@
       --seconds <s> --trace <0|1>
 
 Set-up goes through the program's public path: plan
-(``repro.exec.build_exec_plan``), the benchmark's seeded weights, prune and
+(``repro.exec.build_exec_plan``), the benchmark's seeded weights (drawn by
+the configuration's architecture, ``arch/<name>.py``), prune and
 compress (``prune_params``, ``compress_params``), ``CompressedModel``, and a
 ``repro.launch.mixer.Mixer``; every prompt length of the mix and the decode
 step are warmed.  The window then drives the cell's traffic through the
@@ -28,7 +29,6 @@ asks for.
 from __future__ import annotations
 
 import argparse
-import dataclasses
 import gc
 import glob
 import json
@@ -115,17 +115,6 @@ def configure_cache(root: str) -> str:
     return path
 
 
-def program_config(cfg: dict, dims: dict):
-    from repro.configs import get_config
-    base = get_config(cfg["program_arch"])
-    return dataclasses.replace(
-        base, n_layers=dims["n_layers"], d_model=dims["d_model"],
-        n_heads=dims["n_heads"], n_kv_heads=dims["n_kv_heads"],
-        d_head=dims["head_dim"], d_ff=dims["d_ff"], vocab=dims["vocab"],
-        rope_fraction=dims["rope_fraction"], rope_base=dims["rope_base"],
-        norm_eps=dims["norm_eps"], tie_embeddings=True)
-
-
 def build_plan(pcfg, cfg: dict):
     from repro.core.cosearch import CoSearchConfig
     from repro.core.engine import EngineConfig
@@ -174,16 +163,17 @@ def setup(c: dict, seed: int, annotate: bool) -> dict:
     import weights
     from window import Driver
 
-    cfg, mix = c["config"], c["mix"]
-    dims = spec.dims(cfg)
+    cfg, mix, arch = c["config"], c["mix"], c["arch"]
+    dims = arch.dims(cfg)
     density = cfg["sparsity"]["density"]
-    pcfg = program_config(cfg, dims)
+    pcfg = arch.program_config(cfg, dims)
     t = time.perf_counter()
     plan = build_plan(pcfg, cfg)
     plan_s = time.perf_counter() - t
-    masks = weights.masks(cfg, dims)
-    w = weights.make(seed, dims, masks, density)
-    tree = weights.program_tree(w)
+    roles = arch.roles(dims)
+    masks = weights.masks(cfg, roles)
+    w = arch.make(seed, dims, masks, density)
+    tree = arch.program_tree(w)
     del w
     check_tree(tree, pcfg)
     pruned = prune_params(tree, plan, pcfg)
@@ -198,7 +188,7 @@ def setup(c: dict, seed: int, annotate: bool) -> dict:
         ch = op.choice
         log(f"  plan {op.role:<11} {op.n}x{op.k} kernel={ch.kind} "
             f"block={ch.block_n}x{ch.block_k} format={ch.format_str}")
-    nnz = weights.nnz_per_layer(dims, masks, density)
+    nnz = weights.nnz_per_layer(roles, masks, density)
     short = 0.0
     for role, sr in cm.stacked.roles.items():
         want = nnz[role] * pcfg.n_layers
@@ -210,10 +200,10 @@ def setup(c: dict, seed: int, annotate: bool) -> dict:
     driver = Driver(mx, stream, Request, annotate=annotate)
     driver.warm(traffic.prompt_lengths(mix), mix["loop"] == "closed",
                 np.random.default_rng([seed, 3]))
-    return {"dims": dims, "plan_s": plan_s, "masks": masks,
+    return {"dims": dims, "plan_s": plan_s, "roles": roles, "masks": masks,
             "density": density, "driver": driver, "nnz_short": short,
             "payload_dtype": dtype,
-            "nnzb": weights.nnz_blocks(dims, masks, density),
+            "nnzb": weights.nnz_blocks(roles, masks, density),
             "nnz_layer": sum(nnz.values())}
 
 
@@ -236,10 +226,10 @@ def end_to_end(rec, names: list[str]) -> dict:
     for name in names:
         if name == "output_tok_per_s":
             out[name] = (rec.tokens / window, "tokens/s")
-        elif name == "itl_p50_ms":
-            out[name] = (1e3 * quantile(rec.gaps, 0.5), "ms")
-        elif name == "itl_p95_ms":
-            out[name] = (1e3 * quantile(rec.gaps, 0.95), "ms")
+        elif name.startswith("itl_p") and name.endswith("_ms"):
+            # a percentile of the gaps between a request's tokens, by name
+            q = float(name[len("itl_p"):-len("_ms")]) / 100
+            out[name] = (1e3 * quantile(rec.gaps, q), "ms")
     return out
 
 
@@ -261,7 +251,10 @@ def main(argv=None, *, root: str = spec.ROOT, here: str = spec.HERE,
     if not os.path.isdir(os.path.join(root, "src", "repro")):
         fail(f"the program (src/repro) is not in {root}")
     sys.path.insert(0, os.path.join(root, "src"))
-    c = spec.cell(spec.benchmark(bench_root or root), args.workload, here)
+    try:
+        c = spec.cell(spec.benchmark(bench_root or root), args.workload, here)
+    except (ValueError, OSError) as e:
+        fail(str(e))
     controls = [x for x in args.control.split(",") if x]
     unknown = set(controls) - set(c["config"]["precision"]["controls"])
     if unknown:
@@ -333,10 +326,15 @@ def main(argv=None, *, root: str = spec.ROOT, here: str = spec.HERE,
                "peak": counts.peaks(device["kind"]),
                "kernel_roles": roles, "n_layers": st["dims"]["n_layers"],
                "nnz_layer": st["nnz_layer"],
-               "weight_bytes": counts.step_weight_bytes(
-                   roles, mx.model.stacked, mx.params, st["dims"]),
                "kv_bytes_per_position": counts.kv_bytes_per_position(
                    mx.cache)}
+        # the architecture counts the work from what was served; the
+        # served arrays stay out of ``ctx``, which outlives the program
+        served = dict(ctx, stacked=mx.model.stacked, params=mx.params)
+        ctx["weight_bytes"] = c["arch"].step_weight_bytes(served)
+        ctx["decode_flops"] = c["arch"].decode_flops(served)
+        ctx["decode_kv_bytes"] = c["arch"].decode_kv_bytes(served)
+        del served
         kernels = sorted(sp.kernels for sp in tr.steps) or [0]
         log(f"trace: {tr.window_s:.2f}s, {len(tr.steps)} steps, "
             f"{len(tr.admits)} admissions; Mosaic kernel events per step "
@@ -364,12 +362,12 @@ def main(argv=None, *, root: str = spec.ROOT, here: str = spec.HERE,
     del mx, driver, st["driver"]
     gc.collect()
     import check
-    import weights
-    from reference import gqa
+    arch = c["arch"]
     samples = check.sample(finished, results, args.seed)
-    w = weights.make(args.seed, st["dims"], st["masks"], st["density"])
+    w = arch.make(args.seed, st["dims"], st["masks"], st["density"])
     t = time.perf_counter()
-    g = check.gaps(w, samples, st["dims"], c["mix"]["max_len"], controls)
+    g = check.gaps(arch.logits, w, samples, st["dims"], c["mix"]["max_len"],
+                   controls)
     log(f"reference: {len(samples)} requests from {g['slots']} slots, "
         f"{g['tokens']} served tokens, {time.perf_counter() - t:.2f}s")
     precision, limits = c["config"]["precision"], c["config"]["limits"]
@@ -391,7 +389,7 @@ def main(argv=None, *, root: str = spec.ROOT, here: str = spec.HERE,
         log(f"program: correct {out['correct']}")
         checks, verdicts = {}, []
         for name in controls:
-            ck = checks_of(g["controls"][name], gqa.CONTROL_DTYPES[name])
+            ck = checks_of(g["controls"][name], arch.CONTROL_DTYPES[name])
             verdicts.append(check.judge(ck))
             log(f"control {name}: correct {verdicts[-1]}")
             checks.update({f"{name}.{k}": v for k, v in ck.items()})

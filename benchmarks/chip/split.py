@@ -37,12 +37,6 @@ import scopes  # noqa: E402
 import spec  # noqa: E402
 import tracing  # noqa: E402
 
-#: Scopes of the decode step read per step: attention, the KV write, the
-#: head, and each projection role.
-STEP_SCOPES = scopes.SCOPES + ("head", "attn.wq", "attn.wk", "attn.wv",
-                               "attn.wo", "ffn.w_gate", "ffn.w_up",
-                               "ffn.w_down")
-
 
 def profile(win, seconds: float, trace_dir: str):
     """One profiled window; the trace's path and the steps' host gaps."""
@@ -96,7 +90,11 @@ def main(argv=None, *, root: str = spec.ROOT, here: str = spec.HERE,
     device = run.device_info(chips, require_chip)
     if cache:
         run.configure_cache(root)
-    win = run.setup(c, args.seed, annotate=True)["driver"]
+    st = run.setup(c, args.seed, annotate=True)
+    win = st["driver"]
+    # scopes read per step: attention, the KV write, the head, and each
+    # projection role of the architecture
+    step_scopes = scopes.SCOPES + ("head",) + tuple(st["roles"])
     out = {"workload": args.workload, "seed": args.seed, "device": device}
 
     trace_dir = os.path.join(root, ".bench_trace")
@@ -123,7 +121,7 @@ def main(argv=None, *, root: str = spec.ROOT, here: str = spec.HERE,
     with open(os.path.join(args.out, f"{args.workload}.hlo.txt"), "w") as f:
         f.write(hlo)
     try:
-        sp = scopes.reduce(kept, hlo, chips, scopes=STEP_SCOPES)
+        sp = scopes.reduce(kept, hlo, chips, scopes=step_scopes)
     finally:
         os.remove(kept)
     out.update(scope_ms=sp.scope_ms, idle_ms=sp.idle_ms,
@@ -133,7 +131,7 @@ def main(argv=None, *, root: str = spec.ROOT, here: str = spec.HERE,
     out["top_ops"] = []
     for op, secs in on.breakdown["device_ops"]:
         instr = op.split(" ", 1)[0]
-        held = sorted({next((s for s in STEP_SCOPES
+        held = sorted({next((s for s in step_scopes
                              if scopes.has_scope(p, s)), "-")
                        for p in fused.get(instr, ())})
         out["top_ops"].append({"op": op, "s": secs,

@@ -1,6 +1,7 @@
 """Shared set-up of the benchmark's CPU tests: the harness on ``sys.path``,
 and a tiny copy of the benchmark (the chatglm3-6b configuration cut to
-d_model 256, both mixes cut to 4 slots) that a CPU run can hold."""
+d_model 256, both mixes cut to 4 slots, the architectures and metric
+readers as they are) that a CPU run can hold."""
 
 import json
 import os
@@ -28,9 +29,9 @@ def tiny_dims(cfg: dict) -> dict:
 
 @pytest.fixture
 def tiny_bench(tmp_path):
-    """A benchmark directory (``BENCHMARK.json``, configs, traffic, metrics)
-    whose cells serve a tiny model; returns its path."""
-    for sub in ("configs", "traffic", "metrics"):
+    """A benchmark directory (``BENCHMARK.json``, configs, traffic, arch,
+    metrics) whose cells serve a tiny model; returns its path."""
+    for sub in ("configs", "traffic"):
         (tmp_path / sub).mkdir()
     with open(os.path.join(HERE, "configs", "chatglm3-6b.json")) as f:
         cfg = tiny_dims(json.load(f))
@@ -45,9 +46,9 @@ def tiny_bench(tmp_path):
         if mix["loop"] == "open":
             mix["rate_per_s"] = 2.0
         (tmp_path / "traffic" / f"{name}.json").write_text(json.dumps(mix))
-    for f in os.listdir(os.path.join(HERE, "metrics")):
-        if f.endswith(".py"):
-            shutil.copy(os.path.join(HERE, "metrics", f), tmp_path / "metrics")
+    for sub in ("arch", "metrics"):
+        shutil.copytree(os.path.join(HERE, sub), tmp_path / sub,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     with open(os.path.join(os.path.dirname(os.path.dirname(HERE)),
                            "BENCHMARK.json")) as f:
         bench = json.load(f)

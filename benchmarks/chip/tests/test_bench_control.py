@@ -90,7 +90,7 @@ def test_token_altered_where_it_is_produced_fails(tiny_bench, capsys):
 def test_open_loop_run_reports_its_metrics(tiny_bench, capsys):
     out = _run(tiny_bench, capsys, workload="chatglm3-6b.chat")
     assert out["correct"] is True
-    assert set(out["metrics"]) == {"itl_p50_ms", "itl_p95_ms", "setup_s"}
+    assert set(out["metrics"]) == {"itl_p50_ms", "itl_p99_ms", "setup_s"}
     assert all(np.isfinite(m["value"]) and m["value"] > 0
                for m in out["metrics"].values())
     assert out["device"]["count"] == 1

@@ -11,6 +11,7 @@ import counts
 import spec
 
 CHATGLM = spec.dims(spec.config("chatglm3-6b"))
+GQA = spec.arch("gqa")
 
 
 def _role(itemsize):
@@ -59,8 +60,8 @@ def test_unknown_device_raises():
 
 def test_decode_flops_count_weights_head_and_context():
     dims = dict(CHATGLM, n_layers=1)
-    one = counts.decode_token_flops(dims, 1000, [1])
-    two = counts.decode_token_flops(dims, 1000, [1, 101])
+    one = GQA.decode_token_flops(dims, 1000, [1])
+    two = GQA.decode_token_flops(dims, 1000, [1, 101])
     head = 2 * dims["vocab"] * dims["d_model"]
     per_ctx = 4 * dims["n_heads"] * dims["head_dim"]
     assert one == 2 * 1000 + head + per_ctx
@@ -107,8 +108,9 @@ def test_step_bytes_count_kernel_payload_dense_roles_and_head():
               "blocks": {"attn": {"wq": np.zeros((2, 8, 16), np.float16)},
                          "ffn": {"w_up": np.zeros((2, 8, 32), np.float32)}}}
     role = counts.KernelRole("ffn.w_up", 8, 32, 8, 16, 1, 4, 24)
-    got = counts.step_weight_bytes([role], Stacked(), params,
-                                   {"n_layers": 2})
+    got = GQA.step_weight_bytes({"kernel_roles": [role],
+                                 "stacked": Stacked(), "params": params,
+                                 "dims": {"n_layers": 2}})
     assert got == 2 * (8 * 16 * 4 + 24) + 2 * 8 * 16 * 2 + 100 * 8 * 4
     cache = {"self": {"k": np.zeros((2, 4, 64, 2, 16), np.float16),
                       "v": np.zeros((2, 4, 64, 2, 16), np.float16)}}

@@ -29,6 +29,7 @@ def _fixture_ctx() -> dict:
     """A traced run's context: the fixture's trace and a window record of
     matching shape."""
     import counts
+    import spec
     from window import Record
 
     t = tracing.reduce(FIXTURE)
@@ -39,11 +40,15 @@ def _fixture_ctx() -> dict:
     dims = dict(n_layers=2, d_model=512, n_heads=8, n_kv_heads=2,
                 head_dim=64, d_ff=1024, vocab=4096)
     role = counts.KernelRole("ffn.w_up", 512, 1024, 256, 512, 2, 4, 64)
-    return {"rec": rec, "trace": t, "dims": dims, "plan_s": 0.1,
-            "setup_compile_s": 3.0, "peak": counts.peaks("TPU v5 lite"),
-            "kernel_roles": [role], "n_layers": 2, "nnz_layer": 10**6,
-            "weight_bytes": 2 * 4 * 10**6 + 4096 * 512 * 4,
-            "kv_bytes_per_position": 2 * 2 * 64 * 2 * 2}
+    ctx = {"rec": rec, "trace": t, "dims": dims, "plan_s": 0.1,
+           "setup_compile_s": 3.0, "peak": counts.peaks("TPU v5 lite"),
+           "kernel_roles": [role], "n_layers": 2, "nnz_layer": 10**6,
+           "weight_bytes": 2 * 4 * 10**6 + 4096 * 512 * 4,
+           "kv_bytes_per_position": 2 * 2 * 64 * 2 * 2}
+    gqa = spec.arch("gqa")
+    ctx["decode_flops"] = gqa.decode_flops(ctx)
+    ctx["decode_kv_bytes"] = gqa.decode_kv_bytes(ctx)
+    return ctx
 
 
 @pytest.mark.parametrize("name", sorted(FIXTURE_READINGS))

@@ -1,4 +1,5 @@
-"""Configurations, mixes and metrics are found by name, as files."""
+"""Configurations, mixes, architectures and metrics are found by name, as
+files."""
 
 import json
 import os
@@ -34,7 +35,8 @@ def test_new_files_are_found_without_editing_any(tmp_path):
     assert "dummy-mix" in listed["traffic"]
     assert "dummy.metric_ms" in listed["metrics"]
     c = spec.cell(bench, "dummy-model.dummy-mix", str(here))
-    assert spec.dims(c["config"])["n_layers"] == 1
+    assert spec.dims(c["config"], str(here))["n_layers"] == 1
+    assert c["arch"].__file__ == str(here / "arch" / "gqa.py")
     assert c["mix"]["loop"] == "open"
     assert [m["name"] for m in c["per_layer"]] == ["dummy.metric_ms"]
     assert spec.metric_reader("dummy.metric_ms", str(here))({"x": 4}) == 8
@@ -52,6 +54,7 @@ def test_every_named_file_exists():
     assert {m["name"] for m in bench["per_layer"]} <= set(listed["metrics"])
     for c in bench["configs"]:
         cfg = spec.config(c["name"])
+        assert cfg["arch"] in listed["arch"]
         assert c["file"] == os.path.relpath(
             os.path.join(spec.HERE, "configs", c["name"] + ".json"), spec.ROOT)
         assert cfg["source"] == c["source"]

@@ -6,6 +6,7 @@ import jax
 import numpy as np
 import pytest
 
+import spec
 import weights
 from reference import gqa
 
@@ -30,6 +31,8 @@ SMALL = {
 #: their own scale.
 TOL = 4e-2
 
+GQA = spec.arch("gqa")
+
 
 def _program_logits(arch, dims, w, tokens):
     cfg = dataclasses.replace(
@@ -38,7 +41,7 @@ def _program_logits(arch, dims, w, tokens):
         d_head=dims["head_dim"], d_ff=dims["d_ff"], vocab=dims["vocab"],
         rope_fraction=dims["rope_fraction"], rope_base=dims["rope_base"],
         norm_eps=dims["norm_eps"])
-    logits, _ = Model(cfg).prefill(weights.program_tree(w), tokens[None],
+    logits, _ = Model(cfg).prefill(GQA.program_tree(w), tokens[None],
                                    max_len=tokens.shape[0])
     return np.asarray(logits[0])
 
@@ -50,8 +53,8 @@ def _err(got, want):
 @pytest.mark.parametrize("arch", sorted(SMALL))
 def test_reference_matches_dense_model(arch):
     dims = SMALL[arch]
-    masks = {r: (64, 64) for r in weights.ROLES}
-    w = weights.make(7, dims, masks, 0.5)
+    masks = {r: (64, 64) for r in GQA.ROLES}
+    w = GQA.make(7, dims, masks, 0.5)
     tokens = np.random.default_rng(0).integers(0, dims["vocab"], 96)
     rows = np.arange(96)
     ref = np.asarray(gqa.logits(w, tokens, rows, dims))
@@ -64,8 +67,8 @@ def test_reference_matches_dense_model(arch):
 
 def test_padding_does_not_reach_earlier_positions():
     dims = SMALL["chatglm3-6b"]
-    masks = {r: (64, 64) for r in weights.ROLES}
-    w = weights.make(3, dims, masks, 0.5)
+    masks = {r: (64, 64) for r in GQA.ROLES}
+    w = GQA.make(3, dims, masks, 0.5)
     tokens = np.random.default_rng(1).integers(0, dims["vocab"], 100)
     rows = np.arange(100)
     a = np.asarray(gqa.logits(w, tokens, rows, dims, bucket=256))
@@ -75,30 +78,31 @@ def test_padding_does_not_reach_earlier_positions():
 
 def test_weights_are_sparse_at_the_served_blocks():
     dims = SMALL["chatglm3-6b"]
-    masks = {r: (128, 64) for r in weights.ROLES}
+    masks = {r: (128, 64) for r in GQA.ROLES}
     masks["attn.wq"] = (256, 32)
-    w = weights.make(11, dims, masks, 0.5)
+    w = GQA.make(11, dims, masks, 0.5)
     up = np.asarray(w["w_up"][0]).reshape(2, 128, 8, 64)
     nonzero = (np.abs(up).sum(axis=(1, 3)) > 0)
     assert nonzero.sum() == 8                   # exactly half of 16 blocks
     q = np.asarray(w["wq"][1]).reshape(1, 256, 8, 32)
     assert (np.abs(q).sum(axis=(1, 3)) > 0).sum() == 4
-    nnz = weights.nnz_per_layer(dims, masks, 0.5)
+    nnz = weights.nnz_per_layer(GQA.roles(dims), masks, 0.5)
     assert nnz["ffn.w_up"] == int((np.asarray(w["w_up"][0]) != 0).sum())
     assert nnz["attn.wq"] == int((np.asarray(w["wq"][1]) != 0).sum())
-    again = weights.make(11, dims, masks, 0.5)
+    again = GQA.make(11, dims, masks, 0.5)
     assert all(np.array_equal(np.asarray(again[k]), np.asarray(w[k]))
                for k in w)
-    assert not np.array_equal(np.asarray(weights.make(12, dims, masks, 0.5)
+    assert not np.array_equal(np.asarray(GQA.make(12, dims, masks, 0.5)
                                          ["w_up"]), np.asarray(w["w_up"]))
     jax.clear_caches()
 
 
 def test_mask_blocks_come_from_the_configuration():
     dims = SMALL["chatglm3-6b"]
-    blocks = {r: [128, 64] for r in weights.ROLES}
+    roles = GQA.roles(dims)
+    blocks = {r: [128, 64] for r in roles}
     cfg = {"sparsity": {"mask_blocks": blocks}}
-    assert weights.masks(cfg, dims) == {r: (128, 64) for r in weights.ROLES}
+    assert weights.masks(cfg, roles) == {r: (128, 64) for r in roles}
     blocks["ffn.w_down"] = [96, 64]                   # does not tile 512
     with pytest.raises(ValueError, match="ffn.w_down"):
-        weights.masks(cfg, dims)
+        weights.masks(cfg, roles)

@@ -115,6 +115,9 @@ def test_metric_readers_on_the_recorded_trace():
            "kernel_roles": [role], "n_layers": 2, "nnz_layer": 10**6,
            "weight_bytes": 2 * 4 * 10**6 + 4096 * 512 * 4,
            "kv_bytes_per_position": 2 * 2 * 64 * 2 * 2}
+    gqa = spec.arch("gqa")
+    ctx["decode_flops"] = gqa.decode_flops(ctx)
+    ctx["decode_kv_bytes"] = gqa.decode_kv_bytes(ctx)
     bench = spec.benchmark()
     for m in bench["per_layer"]:
         v = spec.metric_reader(m["name"])(ctx)
