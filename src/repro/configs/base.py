@@ -30,11 +30,43 @@ SHAPES: dict[str, ShapeCfg] = {
 
 @dataclasses.dataclass(frozen=True)
 class MoECfg:
+    """Routed experts.  The router scores all ``n_experts``; this layer
+    holds experts ``[first_expert, first_expert + held)`` (expert
+    parallelism: the other experts live on other chips) and adds only their
+    part of the result."""
     n_experts: int
     top_k: int
     d_expert: int               # per-expert FFN hidden size
-    capacity_factor: float = 1.25
-    router_group: int = 256     # tokens per routing group (GShard dispatch)
+    norm_topk_prob: bool = True  # top-k gates renormalised to sum to 1
+    first_expert: int = 0
+    n_held: int = 0             # experts held here; 0: all of them
+
+    @property
+    def held(self) -> int:
+        return self.n_held or self.n_experts
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeCfg:
+    """One layer kind's rotary embedding: default frequencies
+    ``theta^(-2i/rot)``, or YaRN when ``factor`` > 1 (frequencies blended
+    between interpolated and extrapolated over the ``beta_fast`` …
+    ``beta_slow`` rotation range of ``original_max`` positions; cos and sin
+    scaled by ``attention_factor``)."""
+    theta: float = 10_000.0
+    factor: float = 1.0
+    original_max: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+
+@dataclasses.dataclass(frozen=True)
+class AttnKind:
+    """One kind of attention layer: ``window`` > 0 lets query i see keys
+    ``i - window < j <= i``; 0 is full causal attention."""
+    window: int = 0
+    rope: RopeCfg = RopeCfg()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -89,6 +121,10 @@ class ModelConfig:
     window: int = 0             # sliding-window size for local attention
     rope_fraction: float = 1.0  # chatglm applies RoPE to half the head dim
     rope_base: float = 10_000.0
+    # one period of attention-layer kinds, repeated over a uniform stack
+    # (window and RoPE per layer); empty: every layer alike, from ``window``
+    # and ``rope_base``
+    attn_pattern: tuple[AttnKind, ...] = ()
     # encoder-decoder
     enc_layers: int = 0
     enc_seq: int = 0            # fixed encoder length (whisper: 1500 frames)
@@ -105,6 +141,14 @@ class ModelConfig:
         if self.d_head:
             return self.d_head
         return self.d_model // self.n_heads if self.n_heads else 0
+
+    @property
+    def layer_attn(self) -> tuple[AttnKind, ...]:
+        """Each layer's attention kind (``attn_pattern`` repeated), or ()
+        when the stack has no pattern."""
+        pat = self.attn_pattern
+        return tuple(pat[i % len(pat)] for i in range(self.n_layers)) \
+            if pat else ()
 
     @property
     def layer_kinds(self) -> tuple[str, ...]:
@@ -140,7 +184,7 @@ class ModelConfig:
             MatmulRole("attn.wo", nh * h, d),
         ]
         if self.moe:
-            e, f = self.moe.n_experts, self.moe.d_expert
+            e, f = self.moe.held, self.moe.d_expert
             roles += [
                 MatmulRole("moe.w_gate", d, f, fanout=e),
                 MatmulRole("moe.w_up", d, f, fanout=e),
@@ -159,10 +203,11 @@ class ModelConfig:
         """Approximate parameter count (for roofline MODEL_FLOPS)."""
         d, h = self.d_model, self.head_dim
         emb = self.vocab * d * (1 if self.tie_embeddings else 2)
+        held = self.moe.held if self.moe else 0
         per_attn = d * (self.n_heads * h) + 2 * d * (self.n_kv_heads * h) \
             + (self.n_heads * h) * d
         if self.moe:
-            per_ffn = self.moe.n_experts * 3 * d * self.moe.d_expert \
+            per_ffn = held * 3 * d * self.moe.d_expert \
                 + d * self.moe.n_experts
         else:
             per_ffn = 3 * d * self.d_ff if self.d_ff else 0
@@ -186,14 +231,15 @@ class ModelConfig:
             return self.params_count()
         d = self.d_model
         dense = self.params_count() - self.n_layers * (
-            self.moe.n_experts * 3 * d * self.moe.d_expert)
+            self.moe.held * 3 * d * self.moe.d_expert)
         return dense + self.n_layers * self.moe.top_k * 3 * d * self.moe.d_expert
 
     def reduced(self) -> "ModelConfig":
         """Smoke-test configuration: same family, tiny extents."""
         changes: dict = dict(
-            n_layers=min(self.n_layers, 2 + (len(self.hybrid.block)
-                                             if self.hybrid else 0)),
+            n_layers=min(self.n_layers, max(2 + (len(self.hybrid.block)
+                                                 if self.hybrid else 0),
+                                            len(self.attn_pattern))),
             d_model=128,
             n_heads=4,
             n_kv_heads=min(self.n_kv_heads, 2),
@@ -203,10 +249,17 @@ class ModelConfig:
             enc_layers=min(self.enc_layers, 2),
             enc_seq=min(self.enc_seq, 16),
             window=min(self.window, 64) if self.window else 0,
+            attn_pattern=tuple(
+                dataclasses.replace(k, window=min(k.window, 64))
+                for k in self.attn_pattern),
         )
         if self.moe:
-            changes["moe"] = MoECfg(n_experts=8, top_k=2, d_expert=64,
-                                    router_group=16)
+            # 8 experts, with the same share of them held
+            m = self.moe
+            changes["moe"] = dataclasses.replace(
+                m, n_experts=8, top_k=2, d_expert=64,
+                first_expert=m.first_expert * 8 // m.n_experts,
+                n_held=m.n_held * 8 // m.n_experts)
         if self.ssm:
             changes["ssm"] = SSMCfg(d_state=16, d_conv=4, expand=2,
                                     head_dim=32, chunk=16)

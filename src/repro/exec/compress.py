@@ -11,10 +11,12 @@ calibration loop compares the cost model's predictions against.
 Compression is lossless for weights that already carry the plan's sparsity
 structure (block-sparse for bitmap entries, N:M for nm entries);
 :func:`prune_params` produces such weights from a dense pytree.  MoE roles
-fan out per expert (one entry per (layer, role, expert)).
+fan out per held expert (one entry per (layer, role, expert), ``expert``
+counting the experts held from ``MoECfg.first_expert``).
 
 :func:`stack_store` re-lays a per-layer store as a **layer-stacked**
-:class:`StackedStore`: one pytree per role with a leading layer axis,
+:class:`StackedStore`: one pytree per role with a leading layer axis (and
+an expert axis after it for MoE roles),
 padded so every scanned layer shares ONE kernel configuration per role —
 the representation ``jax.lax.scan`` carries through the compiled serving
 block (:class:`repro.exec.dispatch.CompressedModel` prefill/decode).
@@ -141,14 +143,16 @@ def _layer_weight(params: dict, layer: int, role: str, expert: int
 def _check_uniform(cfg: ModelConfig) -> None:
     if cfg.hybrid is not None or cfg.family not in ("dense", "moe", "vlm"):
         raise NotImplementedError(
-            f"exec plane serves uniform attention stacks; {cfg.name} is "
-            f"family={cfg.family!r} hybrid={cfg.hybrid!r}")
+            f"exec plane serves stacks whose layers share parameter shapes "
+            f"(windows and RoPE may differ per layer, ``attn_pattern``); "
+            f"{cfg.name} is family={cfg.family!r} with a hybrid layer "
+            f"pattern {cfg.hybrid!r}")
 
 
 def _fanout(plan_op: OpPlan, cfg: ModelConfig) -> range:
     if plan_op.role.startswith("moe."):
         assert cfg.moe is not None
-        return range(cfg.moe.n_experts)
+        return range(cfg.moe.held)
     return range(-1, 0)          # single entry, expert = -1
 
 
@@ -205,7 +209,9 @@ class StackedRole:
     params pytree itself).  Bitmap payloads are padded to the max non-zero
     block count across layers, so every scanned layer slice has the same
     shape and runs the same kernel grid (``t_max`` is the shared static
-    bound).  Accounting fields are totals over the layer axis: the EXACT
+    bound).  A MoE role's arrays carry an expert axis after the layer axis
+    (``experts`` of them, padded to one bound across layers and experts).
+    Accounting fields are totals over the layer (and expert) axes: the EXACT
     realized encoding (``stored_bits``) stays what the calibration loop
     compares against; ``padded_bits`` is what the padded stacked payload
     actually occupies (the price of shape uniformity)."""
@@ -221,6 +227,7 @@ class StackedRole:
     t_max: int = 1
     n_sel: int = 0
     m_group: int = 0
+    experts: int = 0             # held experts per layer; 0: not a MoE role
     # accounting — totals over all layers
     dense_bits: float = 0.0
     stored_bits: float = 0.0
@@ -263,7 +270,16 @@ class StackedStore:
         return integrity.verify(self)
 
 
-def _stack_bitmap(role: str, entries: list[CompressedTensor]) -> StackedRole:
+def _by_expert(arrays: list, experts: int) -> jnp.ndarray:
+    """Stack per-entry arrays (layer-major, expert-minor) with a leading
+    layer axis, and an expert axis after it when ``experts``."""
+    out = np.stack([np.asarray(a) for a in arrays])
+    return jnp.asarray(out.reshape((-1, experts) + out.shape[1:])
+                       if experts else out)
+
+
+def _stack_bitmap(role: str, entries: list[CompressedTensor],
+                  experts: int) -> StackedRole:
     ds = [e.data for e in entries]
     bn, bk = ds[0].bn, ds[0].bk
     n, k = ds[0].n, ds[0].k
@@ -283,11 +299,11 @@ def _stack_bitmap(role: str, entries: list[CompressedTensor]) -> StackedRole:
     stored = sum(e.stored_bits for e in entries)
     return StackedRole(
         role=role, kind="bitmap", n=n, k=k,
-        data={"blocks": jnp.asarray(np.stack(blocks)),
-              "row_ids": jnp.asarray(np.stack(rows)),
-              "counts": jnp.stack([d.counts for d in ds]),
-              "offsets": jnp.stack([d.offsets for d in ds])},
-        bn=bn, bk=bk,
+        data={"blocks": _by_expert(blocks, experts),
+              "row_ids": _by_expert(rows, experts),
+              "counts": _by_expert([d.counts for d in ds], experts),
+              "offsets": _by_expert([d.offsets for d in ds], experts)},
+        bn=bn, bk=bk, experts=experts,
         t_max=max(max(d.max_per_col for d in ds), 1),
         dense_bits=sum(e.dense_bits for e in entries),
         stored_bits=stored,
@@ -296,25 +312,28 @@ def _stack_bitmap(role: str, entries: list[CompressedTensor]) -> StackedRole:
         decode_units=float(total_nnzb))
 
 
-def _stack_nm(role: str, entries: list[CompressedTensor]) -> StackedRole:
+def _stack_nm(role: str, entries: list[CompressedTensor],
+              experts: int) -> StackedRole:
     ds = [e.data for e in entries]
     stored = sum(e.stored_bits for e in entries)
     return StackedRole(
         role=role, kind="nm", n=ds[0].n, k=ds[0].k,
-        data={"values": jnp.stack([d.values for d in ds]),
-              "indices": jnp.stack([d.indices for d in ds])},
-        n_sel=ds[0].n_sel, m_group=ds[0].m_group,
+        data={"values": _by_expert([d.values for d in ds], experts),
+              "indices": _by_expert([d.indices for d in ds], experts)},
+        n_sel=ds[0].n_sel, m_group=ds[0].m_group, experts=experts,
         dense_bits=sum(e.dense_bits for e in entries),
         stored_bits=stored, padded_bits=stored,
         payload_elems=float(sum(d.values.size for d in ds)),
         decode_units=float(sum(d.indices.size for d in ds)))
 
 
-def _stack_dense(role: str, entries: list[CompressedTensor]) -> StackedRole:
+def _stack_dense(role: str, entries: list[CompressedTensor],
+                 experts: int) -> StackedRole:
     d0 = entries[0].data
     stored = sum(e.stored_bits for e in entries)
     return StackedRole(
         role=role, kind="dense", n=d0.shape[0], k=d0.shape[1], data=None,
+        experts=experts,
         dense_bits=sum(e.dense_bits for e in entries),
         stored_bits=stored, padded_bits=stored,
         payload_elems=float(sum(e.data.size for e in entries)),
@@ -322,32 +341,30 @@ def _stack_dense(role: str, entries: list[CompressedTensor]) -> StackedRole:
 
 
 def stack_store(store: CompressedStore) -> StackedStore:
-    """Re-lay ``store`` with a leading layer axis per role.
-
-    Only non-expert entries stack (MoE expert matmuls execute dense inside
-    the MoE block and their plan entries are accounting-only, exactly as in
-    the per-layer store).  Bitmap payloads pad to the across-layers max
-    non-zero block count; padded blocks are zeros with row id 0 and sit
+    """Re-lay ``store`` with a leading layer axis per role, and an expert
+    axis after it for a MoE role's held experts (arrays (layer, expert,
+    …)).  Bitmap payloads pad to the max non-zero block count across
+    layers and experts; padded blocks are zeros with row id 0 and sit
     beyond every column's ``counts``, so the kernel never accumulates them
     — results are bit-identical to the per-layer dispatch."""
     n_layers = store.plan.n_layers
     by_role: dict[str, list[CompressedTensor]] = {}
     for e in store:
-        if e.expert >= 0:
-            continue
         by_role.setdefault(e.role, []).append(e)
     roles: dict[str, StackedRole] = {}
     for role, entries in by_role.items():
-        entries.sort(key=lambda e: e.layer)
-        if len(entries) != n_layers:
+        entries.sort(key=lambda e: (e.layer, e.expert))
+        experts = len({e.expert for e in entries}) if entries[0].expert >= 0 \
+            else 0
+        if len(entries) != n_layers * max(experts, 1):
             raise ValueError(f"role {role!r} has {len(entries)} entries for "
-                             f"{n_layers} layers")
+                             f"{n_layers} layers of {max(experts, 1)}")
         kind = entries[0].kind
         if any(e.kind != kind for e in entries):
             raise ValueError(f"role {role!r} mixes kinds across layers")
         stack = {"bitmap": _stack_bitmap, "nm": _stack_nm,
                  "dense": _stack_dense}[kind]
-        roles[role] = stack(role, entries)
+        roles[role] = stack(role, entries, experts)
     return StackedStore(plan=store.plan, n_layers=n_layers, roles=roles)
 
 

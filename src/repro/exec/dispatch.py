@@ -209,7 +209,8 @@ def _on_rows(kernel, x2: jax.Array, operands: dict) -> jax.Array:
 
 
 class _Dispatcher:
-    """Per-(layer, role) hook for the UNROLLED reference forward.
+    """Per-(layer, role) hook for the UNROLLED reference forward (expert
+    roles fall through to the dense einsum).
 
     Bitmap kernels use one per-role ``t_max`` (max over layers), so every
     layer of a role shares a single jitted kernel configuration — same
@@ -265,7 +266,9 @@ class _StackedDispatcher:
     """Hook for the SCANNED forward: static kernel configuration from the
     :class:`StackedStore`, per-layer operands from the scan body's
     ``layer_ctx`` slice.  Runs once per role per trace; the compiled scan
-    replays it for every layer with that layer's payload slice."""
+    replays it for every layer with that layer's payload slice.  A MoE
+    role's slice holds every held expert: each is served by its own kernel
+    call, over every row (``models/moe.py``)."""
 
     def __init__(self, stacked: StackedStore):
         self.stacked = stacked
@@ -279,10 +282,11 @@ class _StackedDispatcher:
         x2 = x.reshape(-1, x.shape[-1])
         m = x2.shape[0]
         nl = self.stacked.n_layers
+        calls = nl * max(sr.experts, 1)
         if sr.kind == "dense":
             _record(role, x2, w.shape[-1], w_bits=sr.stored_bits,
                     macs=float(m) * sr.payload_elems, decode_ops=0.0,
-                    layers=nl)
+                    layers=calls)
             return None
         e = L.current_layer_ctx()
         if e is None or role not in e:
@@ -299,12 +303,20 @@ class _StackedDispatcher:
                 return kops.nm_spmm(xs, kops.NMCompressed(
                     values=d["values"], indices=d["indices"],
                     n=sr.n, k=sr.k, n_sel=sr.n_sel, m_group=sr.m_group))
-        y = _guarded_kernel(role, lambda: _on_rows(kernel, x2, e[role]))
+        if sr.experts:
+            # x (T, n) shared by the experts, or (E, T, n) one row each
+            xs = [x if x.ndim == 2 else x[j] for j in range(sr.experts)]
+            y = _guarded_kernel(role, lambda: jnp.stack([_on_rows(
+                kernel, xs[j], {name: a[j] for name, a in e[role].items()})
+                for j in range(sr.experts)]))
+            x2, lead = xs[0], (sr.experts,) + xs[0].shape[:-1]
+        else:
+            y = _guarded_kernel(role, lambda: _on_rows(kernel, x2, e[role]))
         if y is None:                         # guarded kernel failure: dense
             return None
         _record(role, x2, sr.k, w_bits=sr.stored_bits,
-                macs=float(m) * sr.payload_elems,
-                decode_ops=sr.decode_units, layers=nl,
+                macs=float(x2.shape[0]) * sr.payload_elems,
+                decode_ops=sr.decode_units, layers=calls,
                 stream_passes=_row_passes(x2))
         return y.astype(x.dtype).reshape(*lead, y.shape[-1])
 
@@ -356,9 +368,8 @@ class CompressedModel:
     Mirrors :class:`repro.models.transformer.Model`'s serving surface
     (``prefill`` / ``init_cache`` / ``decode_step`` / ``hidden_states``)
     for uniform attention stacks, driving the model's OWN scanned bodies
-    with the layer-stacked store as scan extras — MoE expert matmuls
-    currently execute dense (their plan entries are accounting-only),
-    matching the store's ``kind="dense"`` fall-through."""
+    with the layer-stacked store as scan extras; each held expert's
+    projections are served by its own kernel call."""
 
     model: T.Model
     store: CompressedStore
@@ -414,18 +425,22 @@ class CompressedModel:
         x = L.embed(tokens, params["embed"])
         positions = jnp.arange(s)
         freqs = L.rope_freqs(cfg)
+        layer_xs = L.layer_attn_xs(cfg)
         with active(self.store) as disp:
             for layer in range(cfg.n_layers):
                 disp.layer = layer
                 p = jax.tree.map(lambda a: a[layer], params["blocks"])
-                x = T._attn_layer(x, p, cfg, freqs, positions, causal=True,
-                                  window=cfg.window)
+                lx = None if layer_xs is None else \
+                    jax.tree.map(lambda a: a[layer], layer_xs)
+                fr, scale, win = T._layer_attn(lx, freqs, cfg.window)
+                x = T._attn_layer(x, p, cfg, fr, positions, causal=True,
+                                  window=win, rope_scale=scale)
         return L.rms_norm(x, params["final_norm"], cfg.norm_eps)
 
     def logits(self, params, tokens: jax.Array) -> jax.Array:
         x = self.hidden_states(params, tokens)
         return jnp.einsum("btd,vd->btv", x,
-                          params["embed"].astype(L.COMPUTE_DTYPE))
+                          L.head_table(params).astype(L.COMPUTE_DTYPE))
 
     # -- serving (prefill + KV-cache decode) --------------------------------
     def prefill(self, params, tokens: jax.Array, max_len: int,

@@ -39,9 +39,12 @@ seeded per request and keyed by token index, so a replayed stream
 reproduces exactly regardless of slot placement.
 
 Known limits: encoder-decoder families are not admitted (prefill needs
-encoder frames); non-uniform cache families (ring windows, hybrid, SSM)
-ingest prompts token-by-token on admission — one decode step per prompt
-token — until their one-pass prefill lands (ROADMAP).
+encoder frames); families whose cache is not a full-length KV per layer
+(a stack-wide window ring, hybrid and SSM stacks) ingest prompts
+token-by-token on admission — one decode step per prompt token — until
+their one-pass prefill lands (ROADMAP).  Stacks whose layers differ only in
+window and RoPE (``attn_pattern``) keep full-length caches and prefill in
+one pass.
 """
 
 from __future__ import annotations
@@ -107,8 +110,9 @@ def prefill_request(model, params, prompt: jax.Array, max_len: int,
                     prefill_fn=None, step_fn=None):
     """Batch-1 prefill of one request: (last_logits (V,), single-row cache).
 
-    Prefers the one-pass ``model.prefill``; families without it (ring
-    windows, hybrid, SSM) fall back to the exact token-by-token decode
+    Prefers the one-pass ``model.prefill``; families without it (a
+    stack-wide window ring, hybrid and SSM stacks: ``Model.prefill`` raises
+    ``NotImplementedError``) fall back to the exact token-by-token decode
     ingest.  ``prefill_fn`` / ``step_fn`` accept pre-jitted callables so
     the mixer's per-admission traces are cached across requests."""
     if prompt.ndim != 2 or prompt.shape[0] != 1 or prompt.shape[1] < 1:
@@ -406,6 +410,16 @@ class Mixer:
             if self.active.any():
                 self._step()
         return [self.results[uid] for uid in order]
+
+    def expert_rows(self) -> Optional[np.ndarray]:
+        """Rows the decode steps routed to each held expert, (layers,
+        held), from the cache's device-side counter; None for a model
+        without experts.  Reading it syncs with the device: call it when
+        metrics are collected, never per step."""
+        rows = self.cache.get("moe_rows")
+        if rows is None:
+            return None
+        return np.asarray(rows).reshape(self.model.cfg.n_layers, -1)
 
     def stats(self) -> dict:
         """Stream-level accounting for benchmarks and the CLI."""

@@ -315,6 +315,10 @@ def main() -> None:
             omet.collect_caches(registry)
             if mx is not None:
                 omet.ingest_straggler(registry, mx.straggler)
+                rows = mx.expert_rows()
+                if rows is not None:
+                    omet.ingest_expert_rows(registry, rows,
+                                            cfg.moe.first_expert)
             if ratio is not None:
                 registry.gauge_set("serve_achieved_compression_ratio", ratio)
             registry.save(args.metrics)

@@ -41,7 +41,9 @@ def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     """Online-softmax attention.
 
     q: (B, Sq, H, D); k/v: (B, Skv, H, D) (same H after GQA repeat).
-    ``window`` > 0 restricts attention to the last ``window`` keys (local).
+    ``window`` > 0 restricts attention to the last ``window`` keys (local);
+    a traced ``window`` (the layer scan's per-layer value) does so where it
+    is > 0.
     ``q_offset``: absolute position of q[0] relative to k[0] (prefill = 0
     with Sq == Skv; decode uses decode_attention instead).
     """
@@ -76,7 +78,11 @@ def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             if causal:
                 mask = mask & (kpos[None, None, None, :] <=
                                qpos[None, None, :, None])
-            if window > 0:
+            if isinstance(window, jax.Array):
+                mask = mask & ((window <= 0) | (kpos[None, None, None, :] >
+                                                qpos[None, None, :, None]
+                                                - window))
+            elif window > 0:
                 mask = mask & (kpos[None, None, None, :] >
                                qpos[None, None, :, None] - window)
             s = jnp.where(mask, s, NEG_INF)
@@ -111,12 +117,15 @@ def _valid_mask(s: int, length: jax.Array) -> jax.Array:
 
 
 def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
-                     length: jax.Array) -> jax.Array:
+                     length: jax.Array,
+                     window: Optional[jax.Array] = None) -> jax.Array:
     """One-token attention against a cache, one pass over each KV group.
 
     q: (B, H, D); caches: (B, S, Hkv, D) with H = r·Hkv (r = 1 is MHA);
     ``length``: number of valid cache positions — a scalar, or (B,)
-    per-slot lengths for mixed-position batches.  The query is grouped
+    per-slot lengths for mixed-position batches.  ``window`` (traced,
+    > 0) also hides positions ``< length - window``: the query at
+    position i sees keys ``i - window < j <= i``.  The query is grouped
     to (B, Hkv, r, D) and contracted against the cache in its stored
     layout, so no (B, S, H, D) copy of the cache is built; with S
     model-sharded only the (B, Hkv, r)-sized softmax statistics and
@@ -131,7 +140,11 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     qg = q.reshape(b, hk, r, d)
     scale = 1.0 / math.sqrt(d)
     scores = jnp.einsum("bgrd,bsgd->bgrs", qg, k_cache) * scale
-    valid = _valid_mask(s, length)[:, None, None, :]    # (1 | B, 1, 1, S)
+    valid = _valid_mask(s, length)
+    if window is not None:
+        lo = jnp.where(window > 0, jnp.reshape(length, (-1, 1)) - window, 0)
+        valid = valid & (jnp.arange(s)[None, :] >= lo)
+    valid = valid[:, None, None, :]                     # (1 | B, 1, 1, S)
     scores = jnp.where(valid, scores, NEG_INF)
     w = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
     out = jnp.einsum("bgrs,bsgd->bgrd", w.astype(COMPUTE_DTYPE), v_cache)
@@ -146,7 +159,8 @@ def attention_block(x: jax.Array, p: dict, cfg: ModelConfig,
                     freqs: Optional[jax.Array], positions: jax.Array,
                     causal: bool = True, window: int = 0,
                     kv_override: Optional[tuple[jax.Array, jax.Array]] = None,
-                    return_kv: bool = False):
+                    return_kv: bool = False,
+                    rope_scale: Optional[jax.Array] = None):
     """Training/prefill attention over a full sequence.
 
     ``kv_override`` supplies external K/V inputs (cross-attention).
@@ -162,7 +176,7 @@ def attention_block(x: jax.Array, p: dict, cfg: ModelConfig,
         v = L.proj(x, p["wv"], "attn.wv")
         k = k.reshape(b, s, nk, hd)
         v = v.reshape(b, s, nk, hd)
-        k = apply_rope(k, positions, freqs)
+        k = apply_rope(k, positions, freqs, rope_scale)
     else:
         assert not return_kv, "return_kv only applies to self-attention"
         xkv = kv_override[0]
@@ -171,7 +185,7 @@ def attention_block(x: jax.Array, p: dict, cfg: ModelConfig,
         v = L.proj(xkv, p["wv"], "attn.wv")
         k = k.reshape(b, skv, nk, hd)
         v = v.reshape(b, skv, nk, hd)
-    q = apply_rope(q, positions, freqs)
+    q = apply_rope(q, positions, freqs, rope_scale)
     q = shard(q, "batch", None, "model", None)
     k = shard(k, "batch", None, "model", None)
     rep = nh // max(nk, 1)
@@ -189,6 +203,8 @@ def attention_decode_block(x: jax.Array, p: dict, cfg: ModelConfig,
                            freqs: Optional[jax.Array], pos: jax.Array,
                            k_cache: jax.Array, v_cache: jax.Array,
                            cache_pos: jax.Array,
+                           window: Optional[jax.Array] = None,
+                           rope_scale: Optional[jax.Array] = None,
                            ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Single-token attention step.
 
@@ -196,8 +212,9 @@ def attention_decode_block(x: jax.Array, p: dict, cfg: ModelConfig,
     position for sliding windows; == pos for full caches).  ``pos`` /
     ``cache_pos`` are scalars (lockstep batch) or (B,) per-slot vectors
     (the mixer's mixed-position batch: every row rotates, writes, and
-    masks at its OWN position).  Returns (out (B, d), new_k_cache,
-    new_v_cache).
+    masks at its OWN position).  ``window`` / ``rope_scale``: the layer's
+    traced window and RoPE scale (:func:`repro.models.layers.layer_attn_xs`).
+    Returns (out (B, d), new_k_cache, new_v_cache).
     """
     b, _ = x.shape
     nh, nk, hd = L.eff_heads(cfg.n_heads), cfg.n_kv_heads, cfg.head_dim
@@ -207,8 +224,10 @@ def attention_decode_block(x: jax.Array, p: dict, cfg: ModelConfig,
     k = L.proj(x, p["wk"], "attn.wk")
     v = L.proj(x, p["wv"], "attn.wv")
     pos1 = jnp.reshape(pos, (b, 1)) if pos.ndim else jnp.reshape(pos, (1,))
-    q = apply_rope(q.reshape(b, 1, nh, hd), pos1, freqs).reshape(b, nh, hd)
-    k = apply_rope(k.reshape(b, 1, nk, hd), pos1, freqs).reshape(b, nk, hd)
+    q = apply_rope(q.reshape(b, 1, nh, hd), pos1, freqs,
+                   rope_scale).reshape(b, nh, hd)
+    k = apply_rope(k.reshape(b, 1, nk, hd), pos1, freqs,
+                   rope_scale).reshape(b, nk, hd)
     v = v.reshape(b, nk, hd)
     with jax.named_scope("kv_write"):
         if optflags.enabled("maskedkv") or cache_pos.ndim:
@@ -231,7 +250,7 @@ def attention_decode_block(x: jax.Array, p: dict, cfg: ModelConfig,
     s_max = k_cache.shape[1]
     length = jnp.minimum(pos + 1, s_max)
     with jax.named_scope("attention"):
-        o = decode_attention(q, k_cache, v_cache, length)
+        o = decode_attention(q, k_cache, v_cache, length, window)
     o = o.reshape(b, nh * hd)
     out = L.proj(o, p["wo"], "attn.wo")
     return out, k_cache, v_cache

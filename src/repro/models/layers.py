@@ -14,8 +14,9 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from repro.configs.base import ModelConfig
+from repro.configs.base import ModelConfig, RopeCfg
 from repro.models import optflags
 from repro.models.sharding import shard
 
@@ -55,15 +56,28 @@ def set_proj_hook(fn) -> None:
     _PROJ_HOOK = fn
 
 
+def proj_hooked() -> bool:
+    """Whether a projection hook is installed (the execution plane serves
+    some projections)."""
+    return _PROJ_HOOK is not None
+
+
 def proj(x: jax.Array, w: jax.Array, role: str) -> jax.Array:
     """``x @ w`` over the last axis of ``x`` (the layers' projection shape:
     w is (d_in, d_out)), dispatchable per ``role``; its device ops carry
-    ``role`` as their name scope, dense or kernel-served."""
+    ``role`` as their name scope, dense or kernel-served.
+
+    Expert weights ``w`` (E, d_in, d_out) give (E, T, d_out): every
+    expert's product with ``x`` (T, d_in), or with its own row of ``x``
+    (E, T, d_in)."""
     with jax.named_scope(role):
         if _PROJ_HOOK is not None:
             y = _PROJ_HOOK(x, w, role)
             if y is not None:
                 return y
+        if w.ndim == 3:
+            spec = "td,edf->etf" if x.ndim == 2 else "etd,edf->etf"
+            return jnp.einsum(spec, x, w.astype(COMPUTE_DTYPE))
         return jnp.einsum("...d,df->...f", x, w.astype(COMPUTE_DTYPE))
 
 
@@ -144,6 +158,12 @@ def unembed_loss(x: jax.Array, table: jax.Array, labels: jax.Array,
     return total / (b * n_chunks * chunk)
 
 
+def head_table(params: dict) -> jax.Array:
+    """The output head's (vocab, d) table: ``params["head"]`` when the
+    model has its own, else the embedding (tied)."""
+    return params["head"] if "head" in params else params["embed"]
+
+
 @jax.named_scope("head")
 def logits_head(x: jax.Array, table: jax.Array) -> jax.Array:
     """Decode-time logits for the last position only: (B, V)."""
@@ -155,26 +175,74 @@ def logits_head(x: jax.Array, table: jax.Array) -> jax.Array:
 # RoPE
 # ---------------------------------------------------------------------------
 
+def _rot(cfg: ModelConfig) -> int:
+    rot = int(cfg.head_dim * cfg.rope_fraction)
+    return rot - rot % 2
+
+
 def rope_freqs(cfg: ModelConfig) -> Optional[jax.Array]:
     if cfg.rope_fraction <= 0.0:
         return None
-    rot = int(cfg.head_dim * cfg.rope_fraction)
-    rot -= rot % 2
+    rot = _rot(cfg)
     return cfg.rope_base ** (-jnp.arange(0, rot, 2, dtype=jnp.float32) / rot)
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, freqs: Optional[jax.Array]
-               ) -> jax.Array:
+def rope_inv_freq(rope: RopeCfg, rot: int) -> np.ndarray:
+    """Inverse frequencies (rot/2,) of one layer kind, float64.
+
+    Default: ``theta^(-2i/rot)``.  YaRN (``factor`` > 1): with ``extrap``
+    those and ``interp = extrap / factor``, ``inv_freq = interp·(1-γ) +
+    extrap·γ``, ``γ = 1 - clamp((i - lo)/(hi - lo), 0, 1)``, where ``lo``
+    and ``hi`` are the floor and ceil of the dimension that turns
+    ``beta_fast`` and ``beta_slow`` times over ``original_max`` positions,
+    ``rot·ln(original_max / (β·2π)) / (2 ln θ)``."""
+    extrap = rope.theta ** (-np.arange(0, rot, 2, dtype=np.float64) / rot)
+    if rope.factor <= 1.0:
+        return extrap
+
+    def dim(beta):
+        return rot * math.log(rope.original_max / (beta * 2 * math.pi)) \
+            / (2 * math.log(rope.theta))
+    lo = max(math.floor(dim(rope.beta_fast)), 0)
+    hi = min(math.ceil(dim(rope.beta_slow)), rot - 1)
+    hi = hi + 0.001 if hi == lo else hi
+    ramp = np.clip((np.arange(rot // 2) - lo) / (hi - lo), 0.0, 1.0)
+    gamma = 1.0 - ramp
+    return extrap / rope.factor * (1.0 - gamma) + extrap * gamma
+
+
+def layer_attn_xs(cfg: ModelConfig) -> Optional[dict]:
+    """Per-layer attention settings that ride the layer scan as ``xs``:
+    ``window`` (L,) int32 (0: full causal), RoPE ``freqs`` (L, rot/2) and
+    ``rope_scale`` (L,) float32.  None for a stack without
+    ``attn_pattern``, whose layers share ``cfg.window`` and
+    :func:`rope_freqs`."""
+    kinds = cfg.layer_attn
+    if not kinds:
+        return None
+    rot = _rot(cfg)
+    return {"window": jnp.asarray([k.window for k in kinds], jnp.int32),
+            "freqs": jnp.asarray(np.stack(
+                [rope_inv_freq(k.rope, rot) for k in kinds]), jnp.float32),
+            "rope_scale": jnp.asarray(
+                [k.rope.attention_factor for k in kinds], jnp.float32)}
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, freqs: Optional[jax.Array],
+               scale: Optional[jax.Array] = None) -> jax.Array:
     """x: (..., S, H, D); positions: broadcastable to (..., S).
 
     Applies rotary embedding to the first ``2·len(freqs)`` features of D
-    (``rope_fraction`` < 1 leaves the tail untouched — ChatGLM-style)."""
+    (``rope_fraction`` < 1 leaves the tail untouched — ChatGLM-style);
+    ``scale`` multiplies cos and sin (YaRN's attention factor)."""
     if freqs is None:
         return x
     rot = 2 * freqs.shape[0]
     ang = positions[..., None].astype(jnp.float32) * freqs       # (..., S, rot/2)
     cos = jnp.cos(ang)[..., None, :]
     sin = jnp.sin(ang)[..., None, :]
+    if scale is not None:
+        cos, sin = cos * scale, sin * scale
     xr = x[..., :rot].astype(jnp.float32)
     x1, x2 = xr[..., 0::2], xr[..., 1::2]
     out = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)

@@ -121,8 +121,16 @@ def _uniform_stack(cfg: ModelConfig) -> bool:
 
 def _ffn_apply(x, p, cfg: ModelConfig):
     if cfg.moe:
-        return moe_mod.moe_block(x, p, cfg)
+        return moe_mod.moe_block(x, p, cfg)[0]
     return L.mlp(x, p)
+
+
+def _layer_attn(lx, freqs, window):
+    """(freqs, rope scale, window) of the scanned layer: from its slice of
+    :func:`repro.models.layers.layer_attn_xs`, or the stack's own."""
+    if lx is None:
+        return freqs, None, window
+    return lx["freqs"], lx["rope_scale"], lx["window"]
 
 
 def _seqpar(x):
@@ -135,11 +143,11 @@ def _seqpar(x):
 
 
 def _attn_layer(x, p, cfg, freqs, positions, *, causal=True, window=0,
-                kv_override=None, return_kv=False):
+                kv_override=None, return_kv=False, rope_scale=None):
     h = L.rms_norm(x, p["ln1"], cfg.norm_eps)
     a = attn.attention_block(h, p["attn"], cfg, freqs, positions,
                              causal=causal, window=window,
-                             return_kv=return_kv)
+                             return_kv=return_kv, rope_scale=rope_scale)
     if return_kv:
         a, kv_k, kv_v = a
     x = x + checkpoint_name(a, "ar_out")
@@ -182,21 +190,26 @@ def _run_uniform(x, stacked, cfg: ModelConfig, freqs, positions, kind: str,
     :class:`repro.exec.compress.StackedStore`'s payloads).  It rides the
     scan's xs; the body publishes each layer's slice through
     ``L.layer_ctx`` so the proj hook can resolve layer-varying operands
-    while the compiled graph stays one scanned block."""
+    while the compiled graph stays one scanned block.  A stack with an
+    ``attn_pattern`` also scans each layer's window and RoPE
+    (:func:`repro.models.layers.layer_attn_xs`)."""
     causal = cfg.family != "encdec" or kind != "enc"
+    layer_xs = L.layer_attn_xs(cfg) if kind == "attn" else None
 
     def body(h, sl):
-        p, e = sl
+        p, e, lx = sl
+        fr, scale, win = _layer_attn(lx, freqs, window)
         with L.layer_ctx(e):
             if kind == "ssm":
                 out, _ = _ssm_layer(h, p, cfg)
             else:
-                out = _attn_layer(h, p, cfg, freqs, positions,
-                                  causal=causal, window=window)
+                out = _attn_layer(h, p, cfg, fr, positions,
+                                  causal=causal, window=win,
+                                  rope_scale=scale)
         return out, None
 
     fn = _ckpt(body) if remat else body
-    x, _ = jax.lax.scan(fn, x, (stacked, extras))
+    x, _ = jax.lax.scan(fn, x, (stacked, extras, layer_xs))
     return x
 
 
@@ -263,6 +276,9 @@ class Model:
             "embed": L._init(k_emb, (cfg.vocab, cfg.d_model), scale_axis=1),
             "final_norm": jnp.zeros((cfg.d_model,), jnp.float32),
         }
+        if not cfg.tie_embeddings:
+            params["head"] = L._init(jax.random.fold_in(rng, 5),
+                                     (cfg.vocab, cfg.d_model), scale_axis=1)
         if cfg.family in ("dense", "moe", "vlm") and cfg.hybrid is None:
             params["blocks"] = _stack(
                 k_layers, cfg.n_layers,
@@ -340,7 +356,7 @@ class Model:
     def loss(self, params: PyTree, batch: dict) -> jax.Array:
         x = self.hidden_states(params, batch["tokens"],
                                batch.get("enc_frames"))
-        return L.unembed_loss(x, params["embed"], batch["labels"])
+        return L.unembed_loss(x, L.head_table(params), batch["labels"])
 
     # ---------------- decode ----------------
     @jax.named_scope("prefill")
@@ -354,14 +370,15 @@ class Model:
         written — so ``decode_step(pos=s)`` continues seamlessly.  Returns
         (logits (B, S, V) float32, cache).
 
-        Uniform full-attention stacks only (``window`` rings and
-        hybrid/ssm/encdec states keep the token-by-token path — see
-        ``launch.serve.generate``'s fallback).
+        Uniform stacks only, each layer's K/V kept for every position
+        (a stack-wide ``window`` ring and hybrid/ssm/encdec states keep the
+        token-by-token path — see ``launch.serve.generate``'s fallback); an
+        ``attn_pattern``'s windows mask, over the full cache.
         """
         cfg = self.cfg
         if not _uniform_stack(cfg) or cfg.window:
             raise NotImplementedError(
-                "prefill: uniform full-attention stacks only")
+                "prefill: uniform stacks with full-length caches only")
         b, s = tokens.shape
         if s > max_len:
             raise ValueError(f"prompt ({s}) exceeds max_len ({max_len})")
@@ -370,16 +387,19 @@ class Model:
         freqs = L.rope_freqs(cfg)
 
         def body(h, sl):
-            p, e = sl
+            p, e, lx = sl
+            fr, scale, win = _layer_attn(lx, freqs, 0)
             with L.layer_ctx(e):
-                out, k, v = _attn_layer(h, p, cfg, freqs, positions,
-                                        causal=True, return_kv=True)
+                out, k, v = _attn_layer(h, p, cfg, fr, positions,
+                                        causal=True, window=win,
+                                        return_kv=True, rope_scale=scale)
             return out, (k, v)
 
-        x, (ks, vs) = jax.lax.scan(body, x, (params["blocks"], extras))
+        x, (ks, vs) = jax.lax.scan(
+            body, x, (params["blocks"], extras, L.layer_attn_xs(cfg)))
         x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
         logits = jnp.einsum("btd,vd->btv", x,
-                            params["embed"].astype(L.COMPUTE_DTYPE))
+                            L.head_table(params).astype(L.COMPUTE_DTYPE))
         logits = shard(logits.astype(jnp.float32), "batch", None, "vocab")
         cache = self.init_cache(b, max_len)
         dt = cache["self"]["k"].dtype
@@ -433,7 +453,14 @@ class Model:
                 "cross": kv(cfg.n_layers, cfg.enc_seq),
                 "cross_ready": jnp.zeros((), jnp.int32),
             }
-        return {"self": kv(cfg.n_layers, max_len)}
+        cache = {"self": kv(cfg.n_layers, max_len)}
+        if cfg.moe:
+            # rows the decode steps routed to each held expert, per layer
+            # (layer-major): a device-side counter, read only when metrics
+            # are collected (``Mixer.expert_rows``)
+            cache["moe_rows"] = jnp.zeros((cfg.n_layers * cfg.moe.held,),
+                                          jnp.int32)
+        return cache
 
     @jax.named_scope("decode")
     def decode_step(self, params: PyTree, cache: PyTree, tokens: jax.Array,
@@ -460,19 +487,25 @@ class Model:
         x = jnp.take(params["embed"], tokens, axis=0).astype(L.COMPUTE_DTYPE)
         freqs = L.rope_freqs(cfg)
 
-        def attn_step(h, p, kc, vc, cache_pos):
+        def attn_step(h, p, kc, vc, cache_pos, lx=None):
+            """One layer; returns (h, kc, vc, rows routed to each held
+            expert or None)."""
+            fr, scale, win = _layer_attn(lx, freqs, None)
             hn = L.rms_norm(h[:, None], p["ln1"], cfg.norm_eps)[:, 0]
             y, kc, vc = attn.attention_decode_block(
-                hn, p["attn"], cfg, freqs, pos, kc, vc, cache_pos)
+                hn, p["attn"], cfg, fr, pos, kc, vc, cache_pos,
+                window=win, rope_scale=scale)
             h = h + y
             hn = L.rms_norm(h[:, None], p["ln2"], cfg.norm_eps)[:, 0]
+            rows = None
             if cfg.moe:
-                h = h + moe_mod.moe_decode(hn, p["ffn"], cfg)
+                y, rows = moe_mod.moe_block(hn, p["ffn"], cfg)
+                h = h + y
             elif "payload_gate" in p["ffn"]:
                 h = h + L.sparse_mlp_decode(hn, p["ffn"])
             else:
                 h = h + L.mlp(hn[:, None], p["ffn"])[:, 0]
-            return h, kc, vc
+            return h, kc, vc, rows
 
         if cfg.family == "ssm":
             def body(h, sl):
@@ -493,11 +526,11 @@ class Model:
 
                 def local_body(hh, inner):
                     pp, kk, vv = inner
-                    hh, kk, vv = attn_step(hh, pp, kk, vv, lpos)
+                    hh, kk, vv, _ = attn_step(hh, pp, kk, vv, lpos)
                     return hh, (kk, vv)
                 h, (lk, lv) = jax.lax.scan(
                     local_body, h, (p["local"], lk, lv))
-                h, gk, gv = attn_step(h, p["global"], gk, gv, pos)
+                h, gk, gv, _ = attn_step(h, p["global"], gk, gv, pos)
                 return h, (lk, lv, gk, gv)
 
             lk5 = cache["local"]["k"][: n_super * 5].reshape(
@@ -511,7 +544,7 @@ class Model:
 
             def tail_body(h, sl):
                 p, kk, vv = sl
-                h, kk, vv = attn_step(h, p, kk, vv, lpos)
+                h, kk, vv, _ = attn_step(h, p, kk, vv, lpos)
                 return h, (kk, vv)
             tk = cache["local"]["k"][n_super * 5:]
             tv = cache["local"]["v"][n_super * 5:]
@@ -540,7 +573,7 @@ class Model:
                 p, kk, vv, h1, c1, h2, c2 = sl
                 h, h1, c1 = rec_step(h, p["rec1"], h1, c1)
                 h, h2, c2 = rec_step(h, p["rec2"], h2, c2)
-                h, kk, vv = attn_step(h, p["attn"], kk, vv, apos)
+                h, kk, vv, _ = attn_step(h, p["attn"], kk, vv, apos)
                 return h, (kk, vv, h1, c1, h2, c2)
 
             hs = cache["h"][: 2 * n_super].reshape(
@@ -590,18 +623,21 @@ class Model:
             cache["self"] = {"k": kk, "v": vv}
         else:
             def body(h, sl):
-                p, kk, vv, e = sl
+                p, kk, vv, e, lx = sl
                 cache_pos = pos % kk.shape[1] if cfg.window else pos
                 with L.layer_ctx(e):
-                    h, kk, vv = attn_step(h, p, kk, vv, cache_pos)
-                return h, (kk, vv)
-            x, (kk, vv) = jax.lax.scan(
+                    h, kk, vv, rows = attn_step(h, p, kk, vv, cache_pos, lx)
+                return h, (kk, vv, rows)
+            x, (kk, vv, rows) = jax.lax.scan(
                 body, x, (params["blocks"], cache["self"]["k"],
-                          cache["self"]["v"], extras))
-            cache = {"self": {"k": kk, "v": vv}}
+                          cache["self"]["v"], extras, L.layer_attn_xs(cfg)))
+            new = {"self": {"k": kk, "v": vv}}
+            if rows is not None:
+                new["moe_rows"] = cache["moe_rows"] + rows.reshape(-1)
+            cache = new
 
         x = L.rms_norm(x[:, None], params["final_norm"], cfg.norm_eps)[:, 0]
-        return L.logits_head(x, params["embed"]), cache
+        return L.logits_head(x, L.head_table(params)), cache
 
 
 def _sinusoid(s: int, d: int) -> jax.Array:

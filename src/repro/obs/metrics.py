@@ -307,6 +307,18 @@ def ingest_health(reg: MetricsRegistry, report) -> None:
             reg.counter_inc("serve_verify_failures_total", 1.0, role=role)
 
 
+def ingest_expert_rows(reg: MetricsRegistry, rows,
+                       first_expert: int = 0) -> None:
+    """Fold the routed-row counter in (``Mixer.expert_rows``): the rows
+    the decode steps routed to each held expert, a gauge
+    ``moe_routed_rows`` per ``layer`` and ``expert`` (the router's index,
+    ``first_expert`` onwards), equal to the counter."""
+    for layer, row in enumerate(rows):
+        for j, n in enumerate(row):
+            reg.gauge_set("moe_routed_rows", float(n), layer=layer,
+                          expert=first_expert + j)
+
+
 def collect_caches(reg: MetricsRegistry) -> None:
     """Convenience: ingest both global cache sources (memo + kernel)."""
     ingest_memo_stats(reg)

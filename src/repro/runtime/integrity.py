@@ -219,34 +219,36 @@ def _stacked_role_errors(stacked
 
     Dense-kind roles carry no stacked payload (they ride in the params
     pytree) and are skipped; kernel-backed roles re-derive each layer's
-    logical encoding from the padded slices, so the recorded per-layer
-    digests still apply."""
+    (and each held expert's) logical encoding from the padded slices, so
+    the recorded per-entry digests still apply."""
     recorded = dict(getattr(stacked.plan, "checksums", None) or {})
     for role in sorted(stacked.roles):
         sr = stacked.roles[role]
         if sr.data is None:
             continue
         err: Optional[IntegrityError] = None
+        experts = range(sr.experts) if sr.experts else (-1,)
         try:
             h = hashlib.sha256()
             for layer in range(stacked.n_layers):
-                if sr.kind == "bitmap":
-                    counts = np.asarray(sr.data["counts"][layer])
-                    offsets = np.asarray(sr.data["offsets"][layer])
-                    row_ids = np.asarray(sr.data["row_ids"][layer])
-                    blocks = np.asarray(sr.data["blocks"][layer])
-                    check_bitmap_structure(role, layer, counts, offsets,
-                                           row_ids, blocks,
-                                           sr.n, sr.k, sr.bn, sr.bk)
-                    _digest_bitmap(h, layer, -1, counts, offsets, row_ids,
-                                   blocks, sr.n, sr.k, sr.bn, sr.bk)
-                else:
-                    values = np.asarray(sr.data["values"][layer])
-                    indices = np.asarray(sr.data["indices"][layer])
-                    check_nm_structure(role, layer, values, indices,
-                                       sr.n, sr.k, sr.n_sel, sr.m_group)
-                    _digest_nm(h, layer, -1, values, indices,
-                               sr.n, sr.k, sr.n_sel, sr.m_group)
+                for expert in experts:
+                    at = (layer,) if expert < 0 else (layer, expert)
+                    d = {k: np.asarray(v[at]) for k, v in sr.data.items()}
+                    if sr.kind == "bitmap":
+                        check_bitmap_structure(
+                            role, layer, d["counts"], d["offsets"],
+                            d["row_ids"], d["blocks"], sr.n, sr.k, sr.bn,
+                            sr.bk)
+                        _digest_bitmap(h, layer, expert, d["counts"],
+                                       d["offsets"], d["row_ids"],
+                                       d["blocks"], sr.n, sr.k, sr.bn, sr.bk)
+                    else:
+                        check_nm_structure(role, layer, d["values"],
+                                           d["indices"], sr.n, sr.k,
+                                           sr.n_sel, sr.m_group)
+                        _digest_nm(h, layer, expert, d["values"],
+                                   d["indices"], sr.n, sr.k, sr.n_sel,
+                                   sr.m_group)
             if role in recorded and h.hexdigest() != recorded[role]:
                 err = IntegrityError(role, "checksum_mismatch",
                                      detail="stacked payload bytes differ "

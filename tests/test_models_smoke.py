@@ -28,7 +28,7 @@ def _batch(cfg, b=2, s=32, rng=None):
 
 
 def test_all_archs_registered():
-    assert len(ARCHS) == 10
+    assert len(ARCHS) == 11
 
 
 @pytest.mark.parametrize("arch", ARCHS)
