@@ -20,10 +20,11 @@ Slot model — no new cache layout, the batch axis IS the slot axis:
     slot with :func:`write_slot` — the same primitive
     ``launch.serve.generate`` uses for ragged left-padded prompts.
   * **Decode** calls ``decode_step`` with a ``(B_slots,)`` position
-    VECTOR: RoPE, cache writes, and the causal mask all follow each
-    row's own position (:func:`repro.models.attention
-    .attention_decode_block`), so the step stays ONE compiled function
-    for every slot occupancy.  Free slots ride along pinned at position
+    VECTOR: RoPE, the causal mask (:func:`repro.models.attention
+    .attention_decode_block`) and the cache writes after the layer scan
+    (:func:`repro.models.attention.write_decode_kv`) all follow each
+    row's own position, so the step stays ONE compiled function for
+    every slot occupancy.  Free slots ride along pinned at position
     0 with a pad token; their writes land below any successor's prompt
     and per-slot length masking keeps them (and any stale KV an evicted
     request left behind) out of every softmax.

@@ -18,7 +18,6 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models import layers as L
-from repro.models import optflags
 from repro.models.layers import COMPUTE_DTYPE, apply_rope
 from repro.models.sharding import shard
 
@@ -118,7 +117,9 @@ def _valid_mask(s: int, length: jax.Array) -> jax.Array:
 
 def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                      length: jax.Array,
-                     window: Optional[jax.Array] = None) -> jax.Array:
+                     window: Optional[jax.Array] = None,
+                     new_kv: Optional[tuple[jax.Array, jax.Array]] = None,
+                     cache_pos: Optional[jax.Array] = None) -> jax.Array:
     """One-token attention against a cache, one pass over each KV group.
 
     q: (B, H, D); caches: (B, S, Hkv, D) with H = r·Hkv (r = 1 is MHA);
@@ -131,6 +132,13 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     model-sharded only the (B, Hkv, r)-sized softmax statistics and
     output partials cross shards, never the cache.  Cost is linear in
     S — this is the decode_32k / long_500k step.
+
+    ``new_kv``: the new token's (K, V), each (B, Hkv, D), not yet in the
+    cache.  It takes the place of cache position ``cache_pos`` (scalar or
+    (B,)), which it is to overwrite and which still holds a stale token:
+    the same softmax and sum as over the cache with the token written
+    there, without writing it.  A ``cache_pos`` at or past S hides nothing
+    and adds nothing, as such a write lands nowhere.
     """
     b, s, hk, d = k_cache.shape
     h = q.shape[1]
@@ -145,10 +153,53 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
         lo = jnp.where(window > 0, jnp.reshape(length, (-1, 1)) - window, 0)
         valid = valid & (jnp.arange(s)[None, :] >= lo)
     valid = valid[:, None, None, :]                     # (1 | B, 1, 1, S)
+    if new_kv is not None:
+        # the new token's score takes the column of the slot it is to
+        # overwrite, so the softmax is the one over the written cache
+        k_new, v_new = new_kv
+        at = (jnp.arange(s)[None, :]
+              == jnp.reshape(cache_pos, (-1, 1)))[:, None, None, :]
+        s_new = jnp.einsum("bgrd,bgd->bgr", qg, k_new) * scale
+        scores = jnp.where(at, s_new[..., None], scores)
     scores = jnp.where(valid, scores, NEG_INF)
     w = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
-    out = jnp.einsum("bgrs,bsgd->bgrd", w.astype(COMPUTE_DTYPE), v_cache)
+    w = w.astype(COMPUTE_DTYPE)
+    if new_kv is None:
+        out = jnp.einsum("bgrs,bsgd->bgrd", w, v_cache)
+        return out.reshape(b, h, d).astype(q.dtype)
+    # that column's weight goes to the new value, not the stale one
+    w_new = jnp.sum(jnp.where(at, w, 0), axis=-1)
+    w = jnp.where(at, jnp.zeros((), w.dtype), w)
+    out = jnp.einsum("bgrs,bsgd->bgrd", w, v_cache,
+                     preferred_element_type=jnp.float32)
+    out = out + w_new[..., None].astype(jnp.float32) \
+        * v_new[:, :, None, :].astype(jnp.float32)
     return out.reshape(b, h, d).astype(q.dtype)
+
+
+def write_decode_kv(cache: dict, k_new: jax.Array, v_new: jax.Array,
+                    cache_pos: jax.Array) -> dict:
+    """Write one decode step's new K/V into a layer-stacked cache.
+
+    cache: {"k", "v"} of (L, B, S, Hkv, D); ``k_new`` / ``v_new``:
+    (L, B, Hkv, D), each layer's new token (what
+    :func:`attention_decode_block` returns, stacked by the layer scan);
+    ``cache_pos``: scalar or (B,) position in the cache (a ring's
+    ``pos % S``).  One scatter per K and V at (layer, slot, position),
+    which XLA does in place on a donated cache; a position at or past S
+    writes nothing."""
+    k, v = cache["k"], cache["v"]
+    n, b = k.shape[:2]
+    # (L, B) index points over the three leading axes, so the scatter
+    # needs no transpose of the cache to put its indexed axes first
+    layer = jnp.arange(n)[:, None]
+    row = jnp.arange(b)[None, :]
+    at = jnp.broadcast_to(jnp.asarray(cache_pos), (b,))[None, :]
+    with jax.named_scope("kv_write"):
+        return {"k": k.at[layer, row, at].set(k_new.astype(k.dtype),
+                                              mode="drop"),
+                "v": v.at[layer, row, at].set(v_new.astype(v.dtype),
+                                              mode="drop")}
 
 
 # ---------------------------------------------------------------------------
@@ -165,7 +216,7 @@ def attention_block(x: jax.Array, p: dict, cfg: ModelConfig,
 
     ``kv_override`` supplies external K/V inputs (cross-attention).
     ``return_kv=True`` additionally returns the pre-GQA-repeat (K, V) —
-    post-RoPE K, exactly what :func:`attention_decode_block` writes into
+    post-RoPE K, exactly what :func:`attention_decode_block` returns for
     the decode cache — so prefill can fill the cache in one batched pass."""
     b, s, _ = x.shape
     nh, nk, hd = L.eff_heads(cfg.n_heads), cfg.n_kv_heads, cfg.head_dim
@@ -206,20 +257,24 @@ def attention_decode_block(x: jax.Array, p: dict, cfg: ModelConfig,
                            window: Optional[jax.Array] = None,
                            rope_scale: Optional[jax.Array] = None,
                            ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Single-token attention step.
+    """Single-token attention step; reads the cache and leaves it as it is.
 
-    x: (B, d).  Caches (B, S, Hkv, D) are updated at ``cache_pos`` (ring
-    position for sliding windows; == pos for full caches).  ``pos`` /
-    ``cache_pos`` are scalars (lockstep batch) or (B,) per-slot vectors
-    (the mixer's mixed-position batch: every row rotates, writes, and
-    masks at its OWN position).  ``window`` / ``rope_scale``: the layer's
-    traced window and RoPE scale (:func:`repro.models.layers.layer_attn_xs`).
-    Returns (out (B, d), new_k_cache, new_v_cache).
+    x: (B, d).  Caches (B, S, Hkv, D) hold the tokens before ``pos``; the
+    new token attends to them and to itself.  ``cache_pos`` is where the
+    new token goes (ring position for sliding windows; == pos for full
+    caches): the new token takes that position's place in the softmax,
+    since it holds a stale token until the caller writes the new one
+    (:func:`write_decode_kv`).  ``pos`` / ``cache_pos`` are scalars
+    (lockstep batch) or (B,) per-slot vectors (the mixer's mixed-position
+    batch: every row rotates and masks at its OWN position).  ``window`` /
+    ``rope_scale``: the layer's traced window and RoPE scale
+    (:func:`repro.models.layers.layer_attn_xs`).
+    Returns (out (B, d), k_new, v_new): the new token's post-RoPE K and V,
+    (B, Hkv, D) in the cache's dtype.
     """
     b, _ = x.shape
     nh, nk, hd = L.eff_heads(cfg.n_heads), cfg.n_kv_heads, cfg.head_dim
     pos = jnp.asarray(pos)
-    cache_pos = jnp.asarray(cache_pos)
     q = L.proj(x, p["wq"], "attn.wq")
     k = L.proj(x, p["wk"], "attn.wk")
     v = L.proj(x, p["wv"], "attn.wv")
@@ -228,29 +283,13 @@ def attention_decode_block(x: jax.Array, p: dict, cfg: ModelConfig,
                    rope_scale).reshape(b, nh, hd)
     k = apply_rope(k.reshape(b, 1, nk, hd), pos1, freqs,
                    rope_scale).reshape(b, nk, hd)
-    v = v.reshape(b, nk, hd)
-    with jax.named_scope("kv_write"):
-        if optflags.enabled("maskedkv") or cache_pos.ndim:
-            # one-hot masked blend: elementwise along the (possibly model-
-            # sharded) S axis — no replicate-and-repartition, unlike a
-            # dynamic update at a traced index.  Costs one cache-sized RMW
-            # pass.  A per-slot (B,) cache_pos always takes this path (each
-            # row writes at its own position — dynamic_update_slice cannot).
-            hot = (jnp.arange(k_cache.shape[1])[None, :] ==
-                   jnp.reshape(cache_pos, (-1, 1)))[:, :, None, None]
-            k_cache = jnp.where(hot, k[:, None].astype(k_cache.dtype),
-                                k_cache)
-            v_cache = jnp.where(hot, v[:, None].astype(v_cache.dtype),
-                                v_cache)
-        else:
-            k_cache = jax.lax.dynamic_update_slice_in_dim(
-                k_cache, k[:, None].astype(k_cache.dtype), cache_pos, axis=1)
-            v_cache = jax.lax.dynamic_update_slice_in_dim(
-                v_cache, v[:, None].astype(v_cache.dtype), cache_pos, axis=1)
-    s_max = k_cache.shape[1]
-    length = jnp.minimum(pos + 1, s_max)
+    # rounded as the written cache holds them
+    k_new = k.astype(k_cache.dtype)
+    v_new = v.reshape(b, nk, hd).astype(v_cache.dtype)
+    length = jnp.minimum(pos + 1, k_cache.shape[1])
     with jax.named_scope("attention"):
-        o = decode_attention(q, k_cache, v_cache, length, window)
+        o = decode_attention(q, k_cache, v_cache, length, window,
+                             new_kv=(k_new, v_new), cache_pos=cache_pos)
     o = o.reshape(b, nh * hd)
     out = L.proj(o, p["wo"], "attn.wo")
-    return out, k_cache, v_cache
+    return out, k_new, v_new

@@ -13,10 +13,6 @@ delta.  Flags:
               dim (which forces replicate-and-repartition copies).
   saveremat — remat policy keeps each block's OUTPUT (post-all-reduce), so
               the backward recompute does not replay TP collectives.
-  maskedkv  — decode caches update via a one-hot masked blend instead of
-              dynamic_update_slice: fully shardable along the cache's S
-              axis (no all-gather for S-sharded caches), costs one extra
-              cache-sized elementwise pass.
   sparseffn — serve-time FFN weights stored in the SnipSnap-chosen
               block-bitmap format: payload-only weight streams (gather-BMM
               over non-zero blocks + segment-sum), cutting decode weight
@@ -35,8 +31,8 @@ import threading
 
 _state = threading.local()
 
-ALL_FLAGS = ("padheads", "replkv", "saveremat", "maskedkv", "sparseffn",
-             "seqpar", "bf16params")
+ALL_FLAGS = ("padheads", "replkv", "saveremat", "sparseffn", "seqpar",
+             "bf16params")
 # bf16params — serve with bf16 parameters (cast once at load): decode is a
 #              weight-stream problem; fp32 master copies belong to training.
 

@@ -9,7 +9,9 @@ Design rules:
   * every train-mode layer body is wrapped in ``jax.checkpoint`` (remat) so
     activation memory is O(layers · boundary), not O(layers · internals);
   * decode carries stacked caches (KV rings for local attention, full KV for
-    global, SSM/LRU states) and updates them functionally via scan outputs.
+    global, SSM/LRU states).  SSM/LRU states ride the scans as outputs; a
+    KV cache is read in place by each layer and written once after the
+    scan, one scatter of every layer's new token.
 
 The public surface is :class:`Model` (init / loss / prefill / decode_step /
 init_cache) + :func:`input_specs`.
@@ -487,13 +489,14 @@ class Model:
         x = jnp.take(params["embed"], tokens, axis=0).astype(L.COMPUTE_DTYPE)
         freqs = L.rope_freqs(cfg)
 
-        def attn_step(h, p, kc, vc, cache_pos, lx=None):
-            """One layer; returns (h, kc, vc, rows routed to each held
-            expert or None)."""
+        def attn_step(h, p, kv, cache_pos, lx=None):
+            """One layer against its (k, v) cache slices, which it only
+            reads; returns (h, the new token's k and v, rows routed to
+            each held expert or None)."""
             fr, scale, win = _layer_attn(lx, freqs, None)
             hn = L.rms_norm(h[:, None], p["ln1"], cfg.norm_eps)[:, 0]
-            y, kc, vc = attn.attention_decode_block(
-                hn, p["attn"], cfg, fr, pos, kc, vc, cache_pos,
+            y, k_new, v_new = attn.attention_decode_block(
+                hn, p["attn"], cfg, fr, pos, *kv, cache_pos,
                 window=win, rope_scale=scale)
             h = h + y
             hn = L.rms_norm(h[:, None], p["ln2"], cfg.norm_eps)[:, 0]
@@ -505,8 +508,11 @@ class Model:
                 h = h + L.sparse_mlp_decode(hn, p["ffn"])
             else:
                 h = h + L.mlp(hn[:, None], p["ffn"])[:, 0]
-            return h, kc, vc, rows
+            return h, k_new, v_new, rows
 
+        # Attention caches stay out of the layer scans: each layer reads
+        # its slice of the stacked cache in place, the scans return only
+        # the new token's K/V, and one scatter per cache writes them all.
         if cfg.family == "ssm":
             def body(h, sl):
                 p, st, cv = sl
@@ -517,50 +523,49 @@ class Model:
                 body, x, (params["blocks"], cache["state"], cache["conv"]))
             cache = {"state": st, "conv": cv}
         elif cfg.name.startswith("gemma3"):
-            win = cache["local"]["k"].shape[2]
+            local, glob = cache["local"], cache["global"]
+            win = local["k"].shape[2]
             lpos = jnp.where(win > 0, pos % win, 0)
-            n_super = cache["global"]["k"].shape[0]
+            n_super = glob["k"].shape[0]
 
             def super_body(h, sl):
-                p, lk, lv, gk, gv = sl
+                p, i = sl
 
                 def local_body(hh, inner):
-                    pp, kk, vv = inner
-                    hh, kk, vv, _ = attn_step(hh, pp, kk, vv, lpos)
-                    return hh, (kk, vv)
-                h, (lk, lv) = jax.lax.scan(
-                    local_body, h, (p["local"], lk, lv))
-                h, gk, gv, _ = attn_step(h, p["global"], gk, gv, pos)
-                return h, (lk, lv, gk, gv)
+                    pp, j = inner
+                    hh, kn, vn, _ = attn_step(hh, pp, _layer_kv(local, j),
+                                              lpos)
+                    return hh, (kn, vn)
+                h, lnew = jax.lax.scan(
+                    local_body, h, (p["local"], i * 5 + jnp.arange(5)))
+                h, gk, gv, _ = attn_step(h, p["global"], _layer_kv(glob, i),
+                                         pos)
+                return h, (lnew, (gk, gv))
 
-            lk5 = cache["local"]["k"][: n_super * 5].reshape(
-                (n_super, 5) + cache["local"]["k"].shape[1:])
-            lv5 = cache["local"]["v"][: n_super * 5].reshape(
-                (n_super, 5) + cache["local"]["v"].shape[1:])
-            x, (lk5, lv5, gk, gv) = jax.lax.scan(
-                super_body, x,
-                (params["super"], lk5, lv5,
-                 cache["global"]["k"], cache["global"]["v"]))
+            x, ((lk, lv), (gk, gv)) = jax.lax.scan(
+                super_body, x, (params["super"], jnp.arange(n_super)))
 
             def tail_body(h, sl):
-                p, kk, vv = sl
-                h, kk, vv, _ = attn_step(h, p, kk, vv, lpos)
-                return h, (kk, vv)
-            tk = cache["local"]["k"][n_super * 5:]
-            tv = cache["local"]["v"][n_super * 5:]
-            x, (tk, tv) = jax.lax.scan(tail_body, x, (params["tail"], tk, tv))
+                p, j = sl
+                h, kn, vn, _ = attn_step(h, p, _layer_kv(local, j), lpos)
+                return h, (kn, vn)
+            x, (tk, tv) = jax.lax.scan(
+                tail_body, x,
+                (params["tail"],
+                 jnp.arange(n_super * 5, local["k"].shape[0])))
             cache = {
-                "local": {
-                    "k": jnp.concatenate(
-                        [lk5.reshape((-1,) + lk5.shape[2:]), tk]),
-                    "v": jnp.concatenate(
-                        [lv5.reshape((-1,) + lv5.shape[2:]), tv])},
-                "global": {"k": gk, "v": gv},
+                "local": attn.write_decode_kv(
+                    local, jnp.concatenate([lk.reshape((-1,) + tk.shape[1:]),
+                                            tk]),
+                    jnp.concatenate([lv.reshape((-1,) + tv.shape[1:]), tv]),
+                    lpos),
+                "global": attn.write_decode_kv(glob, gk, gv, pos),
             }
         elif cfg.family == "hybrid":
-            win = cache["attn"]["k"].shape[2]
+            kv = cache["attn"]
+            win = kv["k"].shape[2]
             apos = pos % win
-            n_super = cache["attn"]["k"].shape[0]
+            n_super = kv["k"].shape[0]
 
             def rec_step(h, p, hs, cv):
                 hn = L.rms_norm(h[:, None], p["ln1"], cfg.norm_eps)[:, 0]
@@ -570,19 +575,20 @@ class Model:
                 return h + L.mlp(hn[:, None], p["ffn"])[:, 0], hs, cv
 
             def super_body(h, sl):
-                p, kk, vv, h1, c1, h2, c2 = sl
+                p, i, h1, c1, h2, c2 = sl
                 h, h1, c1 = rec_step(h, p["rec1"], h1, c1)
                 h, h2, c2 = rec_step(h, p["rec2"], h2, c2)
-                h, kk, vv, _ = attn_step(h, p["attn"], kk, vv, apos)
-                return h, (kk, vv, h1, c1, h2, c2)
+                h, kn, vn, _ = attn_step(h, p["attn"], _layer_kv(kv, i),
+                                         apos)
+                return h, (kn, vn, h1, c1, h2, c2)
 
             hs = cache["h"][: 2 * n_super].reshape(
                 (n_super, 2) + cache["h"].shape[1:])
             cv = cache["conv"][: 2 * n_super].reshape(
                 (n_super, 2) + cache["conv"].shape[1:])
-            x, (kk, vv, h1, c1, h2, c2) = jax.lax.scan(
+            x, (kn, vn, h1, c1, h2, c2) = jax.lax.scan(
                 super_body, x,
-                (params["super"], cache["attn"]["k"], cache["attn"]["v"],
+                (params["super"], jnp.arange(n_super),
                  hs[:, 0], cv[:, 0], hs[:, 1], cv[:, 1]))
 
             def tail_body(h, sl):
@@ -596,13 +602,16 @@ class Model:
                 [jnp.stack([h1, h2], 1).reshape((-1,) + h1.shape[1:]), th])
             new_c = jnp.concatenate(
                 [jnp.stack([c1, c2], 1).reshape((-1,) + c1.shape[1:]), tc])
-            cache = {"attn": {"k": kk, "v": vv}, "h": new_h, "conv": new_c}
+            cache = {"attn": attn.write_decode_kv(kv, kn, vn, apos),
+                     "h": new_h, "conv": new_c}
         elif cfg.family == "encdec":
+            kv = cache["self"]
+
             def body(h, sl):
-                p, kk, vv, ck, cv = sl
+                p, i, ck, cv = sl
                 hn = L.rms_norm(h[:, None], p["ln1"], cfg.norm_eps)[:, 0]
-                y, kk, vv = attn.attention_decode_block(
-                    hn, p["attn"], cfg, freqs, pos, kk, vv, pos)
+                y, kn, vn = attn.attention_decode_block(
+                    hn, p["attn"], cfg, freqs, pos, *_layer_kv(kv, i), pos)
                 h = h + y
                 hn = L.rms_norm(h[:, None], p["ln3"], cfg.norm_eps)[:, 0]
                 q = jnp.einsum("bd,de->be", hn,
@@ -614,30 +623,39 @@ class Model:
                     p["cross"]["wo"].astype(L.COMPUTE_DTYPE))
                 hn = L.rms_norm(h[:, None], p["ln2"], cfg.norm_eps)[:, 0]
                 h = h + L.mlp(hn[:, None], p["ffn"])[:, 0]
-                return h, (kk, vv)
-            x, (kk, vv) = jax.lax.scan(
-                body, x, (params["blocks"], cache["self"]["k"],
-                          cache["self"]["v"], cache["cross"]["k"],
-                          cache["cross"]["v"]))
+                return h, (kn, vn)
+            x, (kn, vn) = jax.lax.scan(
+                body, x, (params["blocks"], jnp.arange(kv["k"].shape[0]),
+                          cache["cross"]["k"], cache["cross"]["v"]))
             cache = dict(cache)
-            cache["self"] = {"k": kk, "v": vv}
+            cache["self"] = attn.write_decode_kv(kv, kn, vn, pos)
         else:
+            kv = cache["self"]
+            cache_pos = pos % kv["k"].shape[2] if cfg.window else pos
+
             def body(h, sl):
-                p, kk, vv, e, lx = sl
-                cache_pos = pos % kk.shape[1] if cfg.window else pos
+                p, i, e, lx = sl
                 with L.layer_ctx(e):
-                    h, kk, vv, rows = attn_step(h, p, kk, vv, cache_pos, lx)
-                return h, (kk, vv, rows)
-            x, (kk, vv, rows) = jax.lax.scan(
-                body, x, (params["blocks"], cache["self"]["k"],
-                          cache["self"]["v"], extras, L.layer_attn_xs(cfg)))
-            new = {"self": {"k": kk, "v": vv}}
+                    h, kn, vn, rows = attn_step(h, p, _layer_kv(kv, i),
+                                                cache_pos, lx)
+                return h, (kn, vn, rows)
+            x, (kn, vn, rows) = jax.lax.scan(
+                body, x, (params["blocks"], jnp.arange(kv["k"].shape[0]),
+                          extras, L.layer_attn_xs(cfg)))
+            new = {"self": attn.write_decode_kv(kv, kn, vn, cache_pos)}
             if rows is not None:
                 new["moe_rows"] = cache["moe_rows"] + rows.reshape(-1)
             cache = new
 
         x = L.rms_norm(x[:, None], params["final_norm"], cfg.norm_eps)[:, 0]
         return L.logits_head(x, L.head_table(params)), cache
+
+
+def _layer_kv(cache: dict, i: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """Layer ``i``'s (k, v), each (B, S, Hkv, D), of a layer-stacked
+    cache, read where it lies."""
+    return tuple(jax.lax.dynamic_index_in_dim(cache[n], i, 0, keepdims=False)
+                 for n in ("k", "v"))
 
 
 def _sinusoid(s: int, d: int) -> jax.Array:

@@ -340,6 +340,25 @@ def _edge_case(s: int):
     return k, v
 
 
+def _edge_block(s: int, i: int, win):
+    """The decode block at position i, its token not yet in the cache:
+    one head of width s, zero query and key projections (every score
+    equal) and identity value and output projections, so that the new
+    token's value is one-hot(i).  The cache slot at i holds a stale value,
+    4 · one-hot(i), which the block must not see."""
+    k, v = _edge_case(s)
+    v = v.at[0, i].set(4.0 * v[0, i])
+    cfg = dataclasses.replace(tiny(), d_model=s, n_heads=1, n_kv_heads=1,
+                              d_head=s)
+    eye, zero = jnp.eye(s), jnp.zeros((s, s))
+    p = {"wq": zero, "wk": zero, "wv": eye, "wo": eye}
+    x = jnp.eye(s)[i][None]
+    out, k_new, v_new = attn.attention_decode_block(
+        x, p, cfg, None, jnp.array([i]), k, v, jnp.array([i]), window=win)
+    np.testing.assert_array_equal(np.asarray(v_new)[0, 0], np.eye(s)[i])
+    return np.asarray(out)[0]
+
+
 @pytest.mark.parametrize("i", [WINDOW - 1, WINDOW, WINDOW + 5, 30])
 def test_window_edge_in_decode_and_prefill(i):
     s = 32
@@ -351,7 +370,8 @@ def test_window_edge_in_decode_and_prefill(i):
     pre = np.asarray(attn.chunked_attention(
         jnp.zeros((1, s, 1, s)), k, v, window=win, q_chunk=8,
         kv_chunk=8))[0, i, 0]
-    for out in (dec, pre):
+    blk = _edge_block(s, i, win)         # the new token attends to itself
+    for out in (dec, pre, blk):
         if i - WINDOW >= 0:
             assert out[i - WINDOW] == 0.0          # key i - W: not seen
         assert out[i - WINDOW + 1 if i >= WINDOW else 0] == pytest.approx(
@@ -362,6 +382,9 @@ def test_window_edge_in_decode_and_prefill(i):
     full = np.asarray(attn.decode_attention(q, k, v, jnp.array([i + 1]),
                                             jnp.asarray(0)))[0, 0]
     assert full[0] == pytest.approx(1.0 / (i + 1), rel=2**-8)
+    full = _edge_block(s, i, jnp.asarray(0))
+    assert full[0] == pytest.approx(1.0 / (i + 1), rel=2**-8)
+    assert full[i] == pytest.approx(1.0 / (i + 1), rel=2**-8)
 
 
 # -- RoPE -------------------------------------------------------------------
